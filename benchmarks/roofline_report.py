@@ -8,13 +8,12 @@ read from the dry-run artifacts (no recompilation).
 Flags the dominant term, the MODEL_FLOPS/HLO_FLOPS 'useful compute'
 ratio, and per-device memory vs the 16 GiB v5e HBM budget.
 
-A second section times the *storage* kernels live (ISSUE 6): the real
-Pallas paths — ``path_lookup`` with its VMEM pinned probe, and
-``prefix_search`` — under ``REPRO_FORCE_PALLAS=1`` (interpret mode on
-this CPU container; compiled on TPU) against the jitted XLA references
-(``REPRO_DISABLE_PALLAS=1``).  The interpreter-vs-compiled delta rows
-land in the bench-gate JSON artifact so kernel-path drift is tracked
-per PR even before TPU time.
+A second section times the *storage* kernels live: the real Pallas
+paths — ``path_lookup`` with its VMEM pinned probe, and
+``prefix_search`` — through ``kernels.ops`` (compiled on a TPU backend;
+interpret mode elsewhere, under ``REPRO_FORCE_PALLAS=1``) against the
+jitted XLA references called directly.  Row names carry the mode the
+kernels ran in, read from the backend.
 """
 from __future__ import annotations
 
@@ -50,32 +49,13 @@ def _timed_ms(fn, iters: int = 5) -> float:
     return float(np.median(ts))
 
 
-def _mode_env(mode: str):
-    """Pin the kernels.ops dispatch: "pallas" forces the Pallas kernels
-    (interpret mode off-TPU), "ref" forces the jitted XLA references."""
-    prev = {k: os.environ.get(k)
-            for k in ("REPRO_FORCE_PALLAS", "REPRO_DISABLE_PALLAS")}
-    os.environ.pop("REPRO_FORCE_PALLAS", None)
-    os.environ.pop("REPRO_DISABLE_PALLAS", None)
-    os.environ["REPRO_FORCE_PALLAS" if mode == "pallas"
-               else "REPRO_DISABLE_PALLAS"] = "1"
-    return prev
-
-
-def _restore_env(prev: dict) -> None:
-    for k, v in prev.items():
-        if v is None:
-            os.environ.pop(k, None)
-        else:
-            os.environ[k] = v
-
-
 def storage_kernel_rows(n_keys: int = 2048, n_q: int = 512,
                         n_pin: int = 8, iters: int = 5) -> list[tuple]:
-    """Time the storage kernels on both dispatch paths and report the
-    interpreter-vs-compiled delta (see module docstring)."""
+    """Time the storage kernels against their references (see module
+    docstring)."""
+    import jax
     import jax.numpy as jnp
-    from repro.kernels import ops
+    from repro.kernels import ops, ref
     from repro.kernels.path_lookup import pad_pinned
 
     rng = np.random.default_rng(0)
@@ -108,27 +88,28 @@ def storage_kernel_rows(n_keys: int = 2048, n_q: int = 512,
         lens[i] = len(p)
     prefs_j, lens_j = jnp.asarray(prefs), jnp.asarray(lens)
 
-    def lookup():
-        np.asarray(ops.path_lookup(khi, klo, qhi, qlo, pinned=pinned))
-
-    def prefix():
-        np.asarray(ops.prefix_search(toks_j, prefs_j, lens_j))
-
-    rows, ms = [], {}
-    for mode in ("pallas", "ref"):
-        prev = _mode_env(mode)
-        try:
-            ms[("lookup", mode)] = _timed_ms(lookup, iters)
-            ms[("prefix", mode)] = _timed_ms(prefix, iters)
-        finally:
-            _restore_env(prev)
-    on_tpu = False
+    lookup_ref = jax.jit(ref.path_lookup_pinned_ref)
+    prefix_ref = jax.jit(jax.vmap(ref.prefix_search_ref, in_axes=(None, 0, 0)))
+    fns = {
+        ("lookup", "pallas"): lambda: ops.path_lookup(
+            khi, klo, qhi, qlo, pinned=pinned),
+        ("lookup", "ref"): lambda: lookup_ref(khi, klo, qhi, qlo, *pinned),
+        ("prefix", "pallas"): lambda: ops.prefix_search(
+            toks_j, prefs_j, lens_j),
+        ("prefix", "ref"): lambda: prefix_ref(toks_j, prefs_j, lens_j),
+    }
+    prev = os.environ.get("REPRO_FORCE_PALLAS")
+    os.environ["REPRO_FORCE_PALLAS"] = "1"
     try:
-        import jax
-        on_tpu = jax.devices()[0].platform == "tpu"
-    except Exception:
-        pass
-    kind = "compiled" if on_tpu else "interpret"
+        ms = {key: _timed_ms(lambda f=f: np.asarray(f()), iters)
+              for key, f in fns.items()}
+    finally:
+        if prev is None:
+            os.environ.pop("REPRO_FORCE_PALLAS")
+        else:
+            os.environ["REPRO_FORCE_PALLAS"] = prev
+    kind = "compiled" if jax.default_backend() == "tpu" else "interpret"
+    rows = []
     for op in ("lookup", "prefix"):
         p_ms, r_ms = ms[(op, "pallas")], ms[(op, "ref")]
         rows.append((f"roofline_storage_{op}_pallas_{kind}",

@@ -22,6 +22,7 @@ import table6_ablation  # noqa: E402
 import fig5_scaling  # noqa: E402
 import errorbook_bench  # noqa: E402
 import roofline_report  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 ALL = {
     "table2": lambda: table2_backends.run(),
@@ -45,6 +46,7 @@ SMOKE = {
 
 
 def main() -> None:
+    enable_compile_cache()
     args = sys.argv[1:]
     if args and args[0] == "--smoke":
         for name, fn in SMOKE.items():

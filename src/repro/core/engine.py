@@ -1258,7 +1258,7 @@ class DeviceEngine(QueryEngine):
                     and (P.is_prefix(seg_pref, p) or p == fixed[qi]))
                 out.append(matches if limit is None else matches[:limit])
                 continue
-            hits = np.nonzero(bitmap[:n_paths, qi])[0]
+            hits = np.nonzero(bitmap[qi, :n_paths])[0]
             matches = sorted(st.paths[r] for r in hits)
             out.append(matches if limit is None else matches[:limit])
         return out

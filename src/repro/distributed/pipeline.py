@@ -27,8 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..jax_compat import shard_map
-
 
 @dataclass(frozen=True)
 class PipelineSchedule:
@@ -95,7 +93,7 @@ def pipeline_apply(stage_fn, stage_params, x_micro, sched: PipelineSchedule,
     pspec = P(axis)
     other = tuple(a for a in mesh.axis_names if a != axis)
     del other
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),

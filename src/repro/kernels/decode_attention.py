@@ -24,12 +24,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
                    *, sm_scale: float, block_k: int):
-    """Refs: q (Hg, D) — the query-head group attending one kv head;
-    k/v (block_k, D); o (Hg, D); scalar-prefetch len (1,) in SMEM."""
+    """Refs: len (B·Hkv,) int32 in SMEM (scalar prefetch); q (Hg, D) —
+    the query-head group attending one kv head; k/v (block_k, D); o
+    (Hg, D)."""
     ki = pl.program_id(1)
     nk = pl.num_programs(1)
 
@@ -39,15 +41,17 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    length = len_ref[0]
+    length = len_ref[pl.program_id(0)]
     # skip kv blocks entirely beyond the live prefix
     @pl.when(ki * block_k < length)
     def _body():
         q = q_ref[...].astype(jnp.float32)           # (Hg, D)
         k = k_ref[...].astype(jnp.float32)           # (bk, D)
         v = v_ref[...].astype(jnp.float32)
+        # float32 accuracy on the MXU: the default precision would round
+        # the operands to bfloat16
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            q, k, (((1,), (1,)), ((), ())), precision=_F32,
             preferred_element_type=jnp.float32) * sm_scale   # (Hg, bk)
         k_pos = ki * block_k + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1)
@@ -58,7 +62,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
         p = jnp.exp(s - m_new)
         l_ref[...] = l_ref[...] * alpha + p.sum(axis=1, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            p, v, (((1,), (0,)), ((), ())), precision=_F32,
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
@@ -73,7 +77,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
     jax.jit, static_argnames=("sm_scale", "block_k", "interpret"))
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                      lengths: jax.Array, *, sm_scale: float | None = None,
-                     block_k: int = 256, interpret: bool = True) -> jax.Array:
+                     block_k: int = 256, interpret: bool) -> jax.Array:
     """q: (B, Hq, D); k_cache/v_cache: (B, Hkv, S, D); lengths: (B,) int32.
 
     Grid = (B·Hkv, S/block_k): one program row per (sequence, kv head),
@@ -94,20 +98,18 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     vr = v_cache.reshape(B * Hkv, S, D)
     lens = jnp.repeat(lengths.astype(jnp.int32), Hkv)
 
-    def q_map(bh, ki):
+    def q_map(bh, ki, lens_ref):
         return (bh, 0, 0)
 
-    def kv_map(bh, ki):
+    def kv_map(bh, ki, lens_ref):
         return (bh, ki, 0)
 
     kernel = functools.partial(_decode_kernel, sm_scale=scale, block_k=bk)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=0,
+        num_scalar_prefetch=1,
         grid=(B * Hkv, S // bk),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM, block_shape=(1,),
-                         index_map=lambda bh, ki: (bh,)),
             pl.BlockSpec((None, group, D), q_map),
             pl.BlockSpec((None, bk, D), kv_map),
             pl.BlockSpec((None, bk, D), kv_map),

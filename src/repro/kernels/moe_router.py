@@ -53,7 +53,7 @@ def _router_kernel(logits_ref, w_ref, idx_ref, *, k: int, renormalize: bool):
 @functools.partial(
     jax.jit, static_argnames=("k", "renormalize", "block_t", "interpret"))
 def moe_router(logits: jax.Array, k: int, *, renormalize: bool = True,
-               block_t: int = 256, interpret: bool = True
+               block_t: int = 256, interpret: bool
                ) -> tuple[jax.Array, jax.Array]:
     """logits: (T, E) → (weights (T, k) f32, indices (T, k) i32)."""
     T, E = logits.shape

@@ -1,25 +1,21 @@
 """Jit'd dispatch wrappers over the Pallas kernels.
 
-Public entry points used by models/ and core/tensorstore.  Each op
-dispatches to the Pallas kernel when the platform + shape warrant it and
-to the pure-jnp reference otherwise:
+Public entry points used by models/ and core/.  The dispatch rule:
 
-  * On TPU (the target), kernels run compiled (``interpret=False``).
-  * On CPU (this container), kernels run in interpret mode only inside
-    the test suite; production paths (model forward, dry-run lowering)
-    use the references so that XLA sees fusible HLO — interpret-mode
-    pallas inside a 256-device SPMD lowering would be both meaningless
-    and slow.  Set ``REPRO_FORCE_PALLAS=1`` to force kernels everywhere.
-
-The dispatch decision is deliberately centralized here so the hillclimb
-loop can flip implementations per-op and re-lower.
+  * On a TPU backend every op runs its Pallas kernel, compiled
+    (``interpret=False``).  There is no fallback: a shape the kernel
+    cannot take raises.
+  * Elsewhere (CPU) the ops run the jitted pure-jnp references in
+    ``ref.py``, so XLA sees fusible HLO — interpret-mode Pallas inside a
+    256-device SPMD lowering would be both meaningless and slow.  Set
+    ``REPRO_FORCE_PALLAS=1`` to run the kernels in interpret mode instead
+    (the kernel-path test leg).
 """
 from __future__ import annotations
 
 import os
 
 import jax
-import numpy as np
 
 from . import ref
 from .decode_attention import decode_attention as _decode_pallas
@@ -31,18 +27,11 @@ from .rmsnorm import rmsnorm as _rmsnorm_pallas
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except RuntimeError:  # pragma: no cover - uninitialized backend
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _use_pallas() -> bool:
-    if os.environ.get("REPRO_FORCE_PALLAS") == "1":
-        return True
-    if os.environ.get("REPRO_DISABLE_PALLAS") == "1":
-        return False
-    return _on_tpu()
+    return _on_tpu() or os.environ.get("REPRO_FORCE_PALLAS") == "1"
 
 
 # ---------------------------------------------------------------------------
@@ -91,14 +80,15 @@ def rmsnorm(x, scale=None, eps: float = 1e-6, block_t: int = 256):
 
 def path_lookup(keys_hi, keys_lo, q_hi, q_lo, *, pinned=None,
                 block_q: int = 256):
-    """Sorted-table batched GET.  Keys must be pre-padded via pad_keys for
-    the kernel path; the reference accepts any length.  ``pinned`` is the
-    optional VMEM hot-set staging triple (hi, lo, sorted-table position) —
-    the kernel probes it before touching the HBM table; the reference
-    oracle applies the same short-circuit.  The fallback is jitted here —
-    the batched QueryEngine calls this once per engine round trip, so an
-    eagerly-traced fori_loop would dominate the call."""
-    if _use_pallas() and keys_hi.shape[0] % 128 == 0:
+    """Sorted-table batched GET.  The kernel needs keys pre-padded via
+    pad_keys (it raises otherwise); the reference accepts any length.
+    ``pinned`` is the optional VMEM hot-set staging triple (hi, lo,
+    sorted-table position) — the kernel probes it before touching the HBM
+    table; the reference oracle applies the same short-circuit.  The
+    reference is jitted here — the batched QueryEngine calls this once
+    per engine round trip, so an eagerly-traced fori_loop would dominate
+    the call."""
+    if _use_pallas():
         return _lookup_pallas(keys_hi, keys_lo, q_hi, q_lo, pinned=pinned,
                               block_q=block_q, interpret=not _on_tpu())
     if pinned is not None:
@@ -111,11 +101,11 @@ _path_lookup_ref_jit = jax.jit(ref.path_lookup_ref)
 _path_lookup_pinned_ref_jit = jax.jit(ref.path_lookup_pinned_ref)
 
 
-def prefix_search(tokens, prefixes, prefix_lens, *, block_n: int = 1024):
-    """(N, L) × (Q, L) → (N, Q) bitmap.
+def prefix_search(tokens, prefixes, prefix_lens, *, block_n: int = 4096):
+    """(N, L) × (Q, L) → (Q, N) bitmap.
 
     The batched QueryEngine path sends whole prefix batches here, so the
-    fallback vmaps the single-prefix reference over the query axis — one
+    reference vmaps the single-prefix oracle over the query axis — one
     XLA call per batch, matching the kernel's launch granularity."""
     if _use_pallas():
         return _prefix_pallas(tokens, prefixes, prefix_lens,
@@ -125,9 +115,8 @@ def prefix_search(tokens, prefixes, prefix_lens, *, block_n: int = 1024):
 
 @jax.jit
 def _prefix_ref_batched(tokens, prefixes, prefix_lens):
-    cols = jax.vmap(lambda p, n: ref.prefix_search_ref(tokens, p, n))(
-        prefixes, prefix_lens)                       # (Q, N)
-    return cols.T                                    # (N, Q)
+    return jax.vmap(lambda p, n: ref.prefix_search_ref(tokens, p, n))(
+        prefixes, prefix_lens)
 
 
 __all__ = ["attention", "decode_attention", "moe_router", "rmsnorm",

@@ -1,33 +1,40 @@
-"""Batched path-hash lookup (the paper's Q1/GET, TPU-native) — Pallas kernel.
+"""Batched path-hash lookup (the paper's Q1/GET, TPU-native) — Pallas kernels.
 
 The WikiKV point lookup — ``GET(H(π))`` over the sorted 64-bit digest
 table — becomes a *batched* device op: the serving tier resolves a whole
 navigation batch (thousands of concurrent GET/LS steps) in one launch.
 
-Two-level search, designed around the TPU memory hierarchy instead of the
-LSM pread of the paper:
+Two kernels, designed around the TPU memory hierarchy instead of the LSM
+pread of the paper.  The digest table is viewed as (N/TILE, TILE) rows;
+every row's first key is a *fence*.
 
-  level 1 (fences): every ``TILE``-th key is a fence.  The fence column
-    (N/TILE pairs) lives in VMEM; each query finds its tile with a
-    *branch-free broadcast compare* — a (block_q × F) lexicographic
-    ``key < q`` matrix reduced by row-sum.  No gather, pure VPU lanework.
-  level 2 (tiles): each query's candidate tile (TILE consecutive keys) is
-    brought in with one dynamic slice from the HBM-resident key table and
-    compared exactly; the row id (or −1) is emitted.
+  route (levels 0 and 1, pure vector work):
+    level 0 (pinned): the engine stages the wiki's pinned hot set ("/" +
+      every dimension — the paper's L1 cache tier) as a VMEM-resident
+      sub-table of (hi, lo, sorted-table position) triples.  Every query
+      is broadcast-compared against it first; a pinned hit is encoded as
+      ``-1 - position`` and never touches the HBM table, so the hot rows
+      that dominate navigation traffic (every chain starts at "/" and a
+      dimension) cost zero HBM touches.
+    level 1 (fences): the fence column streams through VMEM in
+      ``block_f`` tiles along the grid; each query's tile id is the number
+      of fences <= q minus one, a lexicographic broadcast compare reduced
+      by row-sum and accumulated in the resident output block.  The
+      working set is (block_q, block_f) whatever N is.
+  probe (level 2): the route codes arrive by scalar prefetch in SMEM.
+    For each query of the block, one async copy brings its candidate tile
+    (TILE keys of each digest half) from the HBM table into a VMEM
+    scratch row; after the copies land, one (block_q, TILE) exact compare
+    emits the row id (or −1) for the whole block.
 
 This replaces the per-query binary search (log₂N dependent HBM loads,
-latency-bound) with one VMEM-resident compare + exactly one dynamic slice
-per query — the O(1) storage-round-trip contract of §IV, realized as
-"O(1) HBM touches per query".
+latency-bound) with exactly one tile copy per unpinned query — the O(1)
+storage-round-trip contract of §IV, realized as "O(1) HBM touches per
+query".  ``pinned=None`` degrades to a sentinel table that can never hit.
 
-  level 0 (pinned): the engine stages the wiki's pinned hot set ("/" +
-    every dimension — the paper's L1 cache tier) as a VMEM-resident
-    sub-table of (hi, lo, sorted-table position) triples.  Every query is
-    broadcast-compared against it *first*; a pinned hit emits its
-    position directly and skips the HBM tile slice entirely, so the hot
-    rows that dominate navigation traffic (every chain starts at "/" and
-    a dimension) cost zero HBM touches.  ``pinned=None`` degrades to a
-    sentinel table that can never hit.
+Ordering compares run on int32 after flipping the sign bit of each
+uint32 digest half (order-preserving), so the kernels need no unsigned
+vector compares.
 """
 from __future__ import annotations
 
@@ -41,85 +48,136 @@ from jax.experimental.pallas import tpu as pltpu
 
 TILE = 128
 
-
 #: pinned sub-table allocation granule (lane-friendly; tiny either way)
 PIN_TILE = 8
 
+#: fences compared per route grid step: bounds the (block_q, block_f)
+#: compare matrix in VMEM independently of the table size
+BLOCK_F = 1024
 
-def _lookup_kernel(phi_ref, plo_ref, ppos_ref, fhi_ref, flo_ref,
-                   khi_ref, klo_ref, qhi_ref, qlo_ref,
-                   out_ref, *, n_keys: int, n_fences: int, block_q: int):
-    """Refs: pinned p{hi,lo,pos} (P,) VMEM; fences f{hi,lo} (F,) VMEM;
-    full keys k{hi,lo} (N,) ANY/HBM; queries q{hi,lo} (block_q,) VMEM;
-    out (block_q,) int32."""
+_SIGN = np.uint32(0x80000000)
+#: 0xFFFFFFFF after the sign-bit flip of ``_ordered``
+_MAX_ORDERED = np.int32(0x7FFFFFFF)
+
+
+def _ordered(x: jax.Array) -> jax.Array:
+    """uint32 → int32 with the same ordering (sign-bit flip)."""
+    return jax.lax.bitcast_convert_type(x.astype(jnp.uint32) ^ _SIGN,
+                                        jnp.int32)
+
+
+def _route_kernel(qhi_ref, qlo_ref, fhi_ref, flo_ref,
+                  phi_ref, plo_ref, ppos_ref, code_ref, *, n_fences: int):
+    """Refs: queries q{hi,lo} (block_q, 1); fences f{hi,lo} (1, block_f);
+    pinned p{hi,lo,pos} (1, P); out code (block_q, 1) int32, resident
+    across the fence axis of the grid."""
+    f = pl.program_id(1)
     qhi = qhi_ref[...]
     qlo = qlo_ref[...]
-    fhi = fhi_ref[...]
-    flo = flo_ref[...]
-    # level 0: broadcast-compare against the VMEM pinned hot set.  Pinned
-    # keys are unique, so the masked row-sum selects the hit position.
-    phi = phi_ref[...]
-    plo = plo_ref[...]
-    ppos = ppos_ref[...]
-    pin_eq = (phi[None, :] == qhi[:, None]) & (plo[None, :] == qlo[:, None])
-    pin_hit = jnp.any(pin_eq, axis=1)                      # (block_q,)
-    pin_pos = jnp.sum(jnp.where(pin_eq, ppos[None, :], 0),
-                      axis=1).astype(jnp.int32)
-    # level 1: tile id = (# fences <= q) - 1, lexicographic on uint32 pairs
-    le = (fhi[None, :] < qhi[:, None]) | (
-        (fhi[None, :] == qhi[:, None]) & (flo[None, :] <= qlo[:, None]))
-    tile_idx = jnp.sum(le.astype(jnp.int32), axis=1) - 1   # (block_q,)
-    tile_idx = jnp.clip(tile_idx, 0, n_fences - 1)
+    le = (fhi_ref[...] < qhi) | ((fhi_ref[...] == qhi)
+                                 & (flo_ref[...] <= qlo))
+    count = jnp.sum(le.astype(jnp.int32), axis=1, keepdims=True)
 
-    # level 2: one dynamic slice per query (serial fori over the block —
-    # each iteration is a TILE-wide vector compare, fully in-lane); a
-    # pinned hit skips the HBM slice entirely
-    def body(i, _):
-        @pl.when(pin_hit[i])
-        def _pinned():
-            out_ref[i] = pin_pos[i]
+    @pl.when(f == 0)
+    def _init():
+        code_ref[...] = count
 
-        @pl.when(~pin_hit[i])
-        def _hbm():
-            start = tile_idx[i] * TILE
-            start = jnp.minimum(start, n_keys - TILE)
-            khi = khi_ref[pl.ds(start, TILE)]
-            klo = klo_ref[pl.ds(start, TILE)]
-            hit = (khi == qhi[i]) & (klo == qlo[i])
-            pos = jnp.arange(TILE, dtype=jnp.int32)
-            row = jnp.min(jnp.where(hit, start + pos, jnp.int32(2**31 - 1)))
-            out_ref[i] = jnp.where(jnp.any(hit), row, -1)
+    @pl.when(f > 0)
+    def _accumulate():
+        code_ref[...] += count
 
-        return 0
+    @pl.when(f == pl.num_programs(1) - 1)
+    def _finish():
+        tile = jnp.clip(code_ref[...] - 1, 0, n_fences - 1)
+        # pinned keys are unique, so the masked row-sum selects the hit
+        eq = (phi_ref[...] == qhi) & (plo_ref[...] == qlo)     # (bq, P)
+        hit = jnp.max(eq.astype(jnp.int32), axis=1, keepdims=True) > 0
+        pos = jnp.sum(jnp.where(eq, ppos_ref[...], 0), axis=1,
+                      keepdims=True)
+        code_ref[...] = jnp.where(hit, -1 - pos, tile)
 
-    jax.lax.fori_loop(0, block_q, body, 0)
+
+def _probe_kernel(code_sref, khi_hbm, klo_hbm, qhi_ref, qlo_ref, code_ref,
+                  out_ref, bhi, blo, sem, *, block_q: int):
+    """Refs: code (Qp,) SMEM (scalar prefetch); keys k{hi,lo} (N/TILE,
+    TILE) in HBM; queries q{hi,lo} and code (block_q, 1) VMEM; out
+    (block_q, 1) int32; scratch b{hi,lo} (block_q, TILE) VMEM + two DMA
+    semaphores."""
+    base = pl.program_id(0) * block_q
+
+    def copies(i, tile):
+        return (pltpu.make_async_copy(khi_hbm.at[pl.ds(tile, 1)],
+                                      bhi.at[pl.ds(i, 1)], sem.at[0]),
+                pltpu.make_async_copy(klo_hbm.at[pl.ds(tile, 1)],
+                                      blo.at[pl.ds(i, 1)], sem.at[1]))
+
+    def start(i, carry):
+        tile = code_sref[base + i]
+
+        @pl.when(tile >= 0)
+        def _():
+            for cp in copies(i, tile):
+                cp.start()
+
+        return carry
+
+    def wait(i, carry):
+        tile = code_sref[base + i]
+
+        @pl.when(tile >= 0)
+        def _():
+            for cp in copies(i, tile):
+                cp.wait()
+
+        return carry
+
+    jax.lax.fori_loop(0, block_q, start, 0)
+    jax.lax.fori_loop(0, block_q, wait, 0)
+    code = code_ref[...]                                       # (bq, 1)
+    # rows of pinned queries were never copied; their stale contents are
+    # masked by the code select below
+    eq = (bhi[...] == qhi_ref[...]) & (blo[...] == qlo_ref[...])
+    lane = jax.lax.broadcasted_iota(jnp.int32, eq.shape, 1)
+    first = jnp.min(jnp.where(eq, lane, TILE), axis=1, keepdims=True)
+    found = jnp.where(first < TILE, code * TILE + first, -1)
+    out_ref[...] = jnp.where(code < 0, -1 - code, found)
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "interpret"))
 def path_lookup(keys_hi: jax.Array, keys_lo: jax.Array,
                 q_hi: jax.Array, q_lo: jax.Array, *,
                 pinned: tuple[jax.Array, jax.Array, jax.Array] | None = None,
-                block_q: int = 256, interpret: bool = True) -> jax.Array:
+                block_q: int = 256, interpret: bool) -> jax.Array:
     """keys_{hi,lo}: (N,) uint32 sorted pairs; q_{hi,lo}: (Q,) uint32.
-    Returns (Q,) int32 row ids, −1 on miss.  N is padded to a TILE multiple
-    with max-key sentinels by the caller (ops.pad_keys).
+    Returns (Q,) int32 row ids, −1 on miss.  N must be a TILE multiple:
+    the caller pads with max-key sentinels (``pad_keys``).
 
     ``pinned`` is the optional VMEM hot-set staging: (pin_hi, pin_lo,
     pin_pos) where pin_pos[j] is the *sorted-table position* of the pinned
     key pair — the value the HBM search would have produced.  Free slots
-    hold 0xFFFFFFFF key sentinels (match-proof; see pad_keys)."""
+    hold 0xFFFFFFFF key sentinels (match-proof; see pad_keys).
+
+    ``block_q`` must be a multiple of 128 (or cover the whole batch) for
+    the compiled TPU path."""
     n = keys_hi.shape[0]
-    assert n % TILE == 0, f"key table must be padded to {TILE}: {n}"
+    if n % TILE:
+        raise ValueError(f"key table must be padded to {TILE}: {n}")
     Q = q_hi.shape[0]
     bq = min(block_q, Q)
-    if Q % bq != 0:
-        pad = bq - Q % bq
-        q_hi = jnp.concatenate([q_hi, jnp.zeros((pad,), q_hi.dtype)])
-        q_lo = jnp.concatenate([q_lo, jnp.zeros((pad,), q_lo.dtype)])
-    Qp = q_hi.shape[0]
-    fences_hi = keys_hi[::TILE]
-    fences_lo = keys_lo[::TILE]
-    n_fences = fences_hi.shape[0]
+    Qp = -(-Q // bq) * bq
+    # padded queries are (0, 0): never an FNV digest, sliced off below
+    qhi = jnp.pad(_ordered(q_hi), (0, Qp - Q)).reshape(Qp, 1)
+    qlo = jnp.pad(_ordered(q_lo), (0, Qp - Q)).reshape(Qp, 1)
+    khi = _ordered(keys_hi).reshape(n // TILE, TILE)
+    klo = _ordered(keys_lo).reshape(n // TILE, TILE)
+    n_fences = n // TILE
+    bf = min(BLOCK_F, n_fences)
+    n_fp = -(-n_fences // bf) * bf
+    # fence padding is the (ordered) max-key sentinel: never <= a query
+    fhi = jnp.pad(khi[:, 0], (0, n_fp - n_fences),
+                  constant_values=_MAX_ORDERED).reshape(1, n_fp)
+    flo = jnp.pad(klo[:, 0], (0, n_fp - n_fences),
+                  constant_values=_MAX_ORDERED).reshape(1, n_fp)
     if pinned is None:
         pin_hi = jnp.full((PIN_TILE,), 0xFFFFFFFF, jnp.uint32)
         pin_lo = pin_hi
@@ -127,29 +185,39 @@ def path_lookup(keys_hi: jax.Array, keys_lo: jax.Array,
     else:
         pin_hi, pin_lo, pin_pos = pinned
     n_pin = pin_hi.shape[0]
+    phi = _ordered(pin_hi).reshape(1, n_pin)
+    plo = _ordered(pin_lo).reshape(1, n_pin)
+    ppos = pin_pos.astype(jnp.int32).reshape(1, n_pin)
 
-    kernel = functools.partial(
-        _lookup_kernel, n_keys=n, n_fences=n_fences, block_q=bq)
-    out = pl.pallas_call(
-        kernel,
-        grid=(Qp // bq,),
-        in_specs=[
-            pl.BlockSpec((n_pin,), lambda qb: (0,)),
-            pl.BlockSpec((n_pin,), lambda qb: (0,)),
-            pl.BlockSpec((n_pin,), lambda qb: (0,)),
-            pl.BlockSpec((n_fences,), lambda qb: (0,)),
-            pl.BlockSpec((n_fences,), lambda qb: (0,)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec((bq,), lambda qb: (qb,)),
-            pl.BlockSpec((bq,), lambda qb: (qb,)),
-        ],
-        out_specs=pl.BlockSpec((bq,), lambda qb: (qb,)),
-        out_shape=jax.ShapeDtypeStruct((Qp,), jnp.int32),
+    q_spec = pl.BlockSpec((bq, 1), lambda qb, fb: (qb, 0))
+    f_spec = pl.BlockSpec((1, bf), lambda qb, fb: (0, fb))
+    p_spec = pl.BlockSpec((1, n_pin), lambda qb, fb: (0, 0))
+    code = pl.pallas_call(
+        functools.partial(_route_kernel, n_fences=n_fences),
+        grid=(Qp // bq, n_fp // bf),
+        in_specs=[q_spec, q_spec, f_spec, f_spec, p_spec, p_spec, p_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((Qp, 1), jnp.int32),
         interpret=interpret,
-    )(pin_hi, pin_lo, pin_pos, fences_hi, fences_lo,
-      keys_hi, keys_lo, q_hi, q_lo)
-    return out[:Q]
+    )(qhi, qlo, fhi, flo, phi, plo, ppos)
+
+    blk = pl.BlockSpec((bq, 1), lambda qb, code_s: (qb, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    out = pl.pallas_call(
+        functools.partial(_probe_kernel, block_q=bq),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(Qp // bq,),
+            in_specs=[hbm, hbm, blk, blk, blk],
+            out_specs=blk,
+            scratch_shapes=[pltpu.VMEM((bq, TILE), jnp.int32),
+                            pltpu.VMEM((bq, TILE), jnp.int32),
+                            pltpu.SemaphoreType.DMA((2,))],
+        ),
+        out_shape=jax.ShapeDtypeStruct((Qp, 1), jnp.int32),
+        interpret=interpret,
+    )(code.reshape(Qp), khi, klo, qhi, qlo, code)
+    return out[:Q, 0]
 
 
 def pad_pinned(pin_hi: np.ndarray, pin_lo: np.ndarray, pin_pos: np.ndarray

@@ -2,8 +2,8 @@
 
 Each function is the semantic ground truth its kernel is validated
 against (tests/test_kernels.py sweeps shapes/dtypes and asserts
-allclose in interpret mode).  These are also the dispatch fallbacks in
-ops.py for shapes where a kernel launch is not warranted.
+allclose in interpret mode).  ops.py dispatches to them off-TPU; on a
+TPU only the kernels run.
 """
 from __future__ import annotations
 

@@ -35,8 +35,8 @@ def _rmsnorm_kernel_noscale(x_ref, o_ref, *, eps: float):
 @functools.partial(
     jax.jit, static_argnames=("eps", "block_t", "interpret"))
 def rmsnorm(x: jax.Array, scale: jax.Array | None = None,
-            eps: float = 1e-6, block_t: int = 256,
-            interpret: bool = True) -> jax.Array:
+            eps: float = 1e-6, block_t: int = 256, *,
+            interpret: bool) -> jax.Array:
     """x: (..., D); scale: (D,) or None (non-parametric)."""
     orig_shape = x.shape
     D = orig_shape[-1]

@@ -146,8 +146,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
         compiled = lowered.compile()
         result["compile_s"] = round(time.time() - t1, 2)
         mem = compiled.memory_analysis()
-        from ..jax_compat import cost_analysis_dict
-        cost = cost_analysis_dict(compiled)
+        cost = compiled.cost_analysis() or {}
         result["memory"] = _mem_dict(mem)
         result["cost_analysis_raw"] = {
             k: float(v) for k, v in cost.items()

@@ -16,14 +16,18 @@ EXPERIMENTS.md §Perf for the measured alternative of pipelining over it).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-from ..jax_compat import make_mesh as make_mesh_compat  # noqa: F401  (re-export)
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis in Auto (GSPMD-propagated) mode."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -31,7 +35,7 @@ def make_host_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     data = min(data, n)
     model = max(1, min(model, n // max(data, 1)))
-    return make_mesh_compat((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def dp_axes(mesh) -> tuple:

@@ -1,5 +1,5 @@
-"""Serving launcher: build a wiki from a corpus, bring up the engine,
-answer a query batch.
+"""Serving launcher: build a wiki from a corpus, freeze it onto the
+device tier (``DeviceEngine``), answer a query batch.
 
     PYTHONPATH=src python -m repro.launch.serve --queries 8
 """
@@ -9,11 +9,12 @@ import argparse
 
 from repro.configs import get_config
 from repro.core.cache import TieredCache
+from repro.core.engine import DeviceEngine
 from repro.core.oracle import HeuristicOracle
 from repro.core.pipeline import ConstructionPipeline, PipelineConfig
 from repro.data.corpus import AuthTraceConfig, generate_authtrace
 from repro.data.tokenizer import HashTokenizer
-from repro.launch.mesh import make_host_mesh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.runtime.serving import Request, ServingEngine
 
@@ -25,6 +26,7 @@ def main(argv=None):
     ap.add_argument("--batch-size", type=int, default=2)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     docs, questions = generate_authtrace(
         AuthTraceConfig(n_docs=60, n_questions=max(args.queries, 8),
@@ -42,7 +44,8 @@ def main(argv=None):
     params = M.init_params(cfg, seed=args.seed)
     cache = TieredCache(pipe.store, bus=pipe.bus)
     cache.prewarm()
-    engine = ServingEngine(cfg, params, tok, pipe.store, oracle,
+    device = DeviceEngine.from_store(pipe.store)
+    engine = ServingEngine(cfg, params, tok, device, oracle,
                            cache=cache, batch_size=args.batch_size,
                            max_len=256)
     reqs = [Request(rid=q.qid, query=q.text, max_new_tokens=8)

@@ -291,9 +291,7 @@ def _active_params(cfg: ModelConfig) -> float:
     # count full tree, then correct the MoE expert stacks
     total = 0.0
     tree = abstract_params(cfg)
-    # jax.tree.flatten_with_path landed after the pinned 0.4.37; the
-    # tree_util spelling exists across every supported version
-    flat, _ = jax.tree_util.tree_flatten_with_path(tree)
+    flat, _ = jax.tree.flatten_with_path(tree)
     for path, leaf in flat:
         n = float(np.prod(leaf.shape))
         keys = "/".join(str(p) for p in path)

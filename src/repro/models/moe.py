@@ -38,8 +38,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ..jax_compat import shard_map
-
 from ..kernels import ops
 from .config import ModelConfig
 from .layers import FSDP, TP, _dtype, dense_init, mlp_apply, mlp_init
@@ -175,7 +173,7 @@ def moe_apply(params: dict, x: jax.Array, cfg: ModelConfig,
                   if (B * S) % max(dp_size, 1) == 0 and B * S >= dp_size
                   else P(None, None))
         body = partial(_moe_shard_body, cfg=cfg, ep_shards=ep, axis=TP)
-        out = shard_map(
+        out = jax.shard_map(
             body, mesh=mesh,
             in_specs=(x_spec, P(None, None),
                       P(TP, None, None), P(TP, None, None), P(TP, None, None)),

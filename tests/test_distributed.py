@@ -19,9 +19,9 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 os.environ["JAX_PLATFORMS"] = "cpu"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_mesh
 
-mesh = make_mesh_compat((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 
 # (a) EP MoE == local MoE
 from repro.configs import get_config
@@ -44,7 +44,7 @@ print("EP==local OK", err)
 
 # (b) pipeline schedule == sequential
 from repro.distributed.pipeline import PipelineSchedule, pipeline_apply
-pmesh = make_mesh_compat((4, 2), ("pod", "model"))
+pmesh = make_mesh((4, 2), ("pod", "model"))
 S, Mb, F = 4, 6, 8
 ws = jax.random.normal(jax.random.PRNGKey(2), (S, F, F)) * 0.3
 xs = jax.random.normal(jax.random.PRNGKey(3), (Mb, 5, F))

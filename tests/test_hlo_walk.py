@@ -64,8 +64,7 @@ def test_cost_analysis_undercounts_scans_but_walker_does_not():
         return out.sum()
 
     comp = jax.jit(f).lower(x, w).compile()
-    from repro.jax_compat import cost_analysis_dict
-    ca = cost_analysis_dict(comp).get("flops", 0)
+    ca = comp.cost_analysis().get("flops", 0)
     res = HW.walk(comp.as_text())
     one_dot = 2 * 64 * 64 * 64
     assert res.flops == 8 * one_dot
@@ -84,8 +83,8 @@ def test_collective_parsing_on_sharded_program():
     """all-reduce bytes appear under SPMD (uses the session's 1 device —
     sharding over a single-device mesh still emits the SPMD structure; we
     assert no crash and sane totals)."""
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((1,), ("d",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("d",))
     from jax.sharding import NamedSharding, PartitionSpec as P
     x = jax.ShapeDtypeStruct((64, 64), jnp.float32)
     with mesh:

@@ -35,7 +35,8 @@ def test_flash_attention_sweep(B, Hq, Hkv, Sq, Skv, D, causal, dtype):
     q = jax.random.normal(kq, (B, Hq, Sq, D), dtype)
     k = jax.random.normal(kk, (B, Hkv, Skv, D), dtype)
     v = jax.random.normal(kv, (B, Hkv, Skv, D), dtype)
-    out = flash_attention(q, k, v, causal=causal, block_q=32, block_k=32)
+    out = flash_attention(q, k, v, causal=causal, block_q=32, block_k=32,
+                          interpret=True)
     want = ref.attention_ref(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32), **_tol(dtype))
@@ -51,7 +52,7 @@ def test_decode_attention_sweep(B, Hq, Hkv, S, D, block_k, dtype):
     kc = jax.random.normal(kk, (B, Hkv, S, D), dtype)
     vc = jax.random.normal(kv, (B, Hkv, S, D), dtype)
     lens = jnp.asarray([(S // 2 + 7 * i) % S + 1 for i in range(B)], jnp.int32)
-    out = decode_attention(q, kc, vc, lens, block_k=block_k)
+    out = decode_attention(q, kc, vc, lens, block_k=block_k, interpret=True)
     want = ref.decode_attention_ref(q, kc, vc, lens)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32), **_tol(dtype))
@@ -71,7 +72,7 @@ def test_chunked_attention_matches_full():
                                       (512, 8, 2, 256)])
 def test_moe_router_sweep(T, E, k, bt):
     logits = jax.random.normal(KEY, (T, E), jnp.float32)
-    w, i = moe_router(logits, k, block_t=bt)
+    w, i = moe_router(logits, k, block_t=bt, interpret=True)
     wr, ir = ref.moe_router_ref(logits, k)
     np.testing.assert_allclose(np.asarray(w), np.asarray(wr), atol=1e-6)
     assert jnp.all(i == ir)
@@ -85,7 +86,7 @@ def test_rmsnorm_sweep(shape, scaled, dtype):
     x = jax.random.normal(KEY, shape, dtype)
     s = jax.random.normal(jax.random.PRNGKey(1), (shape[-1],), jnp.float32) \
         if scaled else None
-    out = rmsnorm(x, s, block_t=8)
+    out = rmsnorm(x, s, block_t=8, interpret=True)
     want = ref.rmsnorm_ref(x, s)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32), **_tol(dtype))
@@ -103,7 +104,8 @@ def test_path_lookup_sweep(N, Q, bq):
     qhi = np.concatenate([khi[qidx], np.array([1, 2], np.uint32)])
     qlo = np.concatenate([klo[qidx], np.array([3, 4], np.uint32)])
     got = path_lookup(jnp.asarray(khi_p), jnp.asarray(klo_p),
-                      jnp.asarray(qhi), jnp.asarray(qlo), block_q=bq)
+                      jnp.asarray(qhi), jnp.asarray(qlo), block_q=bq,
+                      interpret=True)
     want = ref.path_lookup_ref(jnp.asarray(khi), jnp.asarray(klo),
                                jnp.asarray(qhi), jnp.asarray(qlo))
     assert jnp.all(got == want)
@@ -122,11 +124,12 @@ def test_prefix_search_semantics():
         prefs[i, :len(b)] = np.frombuffer(b, np.uint8)
     plens = np.array([2, 8], np.int32)
     bm = np.asarray(prefix_search(jnp.asarray(toks), jnp.asarray(prefs),
-                                  jnp.asarray(plens), block_n=4))
-    col = bm[:, 0]
+                                  jnp.asarray(plens), block_n=4,
+                                  interpret=True))
+    col = bm[0]
     assert col[1] and col[2] and col[4]
     assert not col[3] and not col[0]       # "/ab" and "/" excluded
-    assert bm[5, 1] and bm[:, 1].sum() == 1
+    assert bm[1, 5] and bm[1].sum() == 1
 
 
 @pytest.mark.parametrize("N,L,Q,bn", [(100, 48, 3, 32), (513, 96, 5, 128)])
@@ -139,11 +142,11 @@ def test_prefix_search_sweep(N, L, Q, bn):
     prefs[:, 0] = ord("/")
     plens = rs.randint(1, 10, size=Q).astype(np.int32)
     got = prefix_search(jnp.asarray(toks), jnp.asarray(prefs),
-                        jnp.asarray(plens), block_n=bn)
+                        jnp.asarray(plens), block_n=bn, interpret=True)
     want = jnp.stack(
         [ref.prefix_search_ref(jnp.asarray(toks), jnp.asarray(prefs[i]),
                                jnp.asarray(plens[i])) for i in range(Q)],
-        axis=1)
+        axis=0)
     assert jnp.all(got == want)
 
 
@@ -172,7 +175,7 @@ def test_path_lookup_pinned_parity(N, Q, n_pin, bq):
     got = path_lookup(jnp.asarray(khi_p), jnp.asarray(klo_p),
                       jnp.asarray(qhi), jnp.asarray(qlo),
                       pinned=tuple(jnp.asarray(a) for a in pinned),
-                      block_q=bq)
+                      block_q=bq, interpret=True)
     oracle = ref.path_lookup_pinned_ref(
         jnp.asarray(khi), jnp.asarray(klo), jnp.asarray(qhi),
         jnp.asarray(qlo), *(jnp.asarray(a) for a in pinned))
