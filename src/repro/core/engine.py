@@ -47,7 +47,6 @@ from __future__ import annotations
 import bisect
 import heapq
 import threading
-import time
 from dataclasses import dataclass, field
 from typing import Callable, Iterator, Optional, Sequence
 
@@ -732,6 +731,14 @@ def _token_hash(token: str) -> int:
     return P.path_hash(token)
 
 
+def _upload(sp, *arrays: np.ndarray) -> list:
+    """Copy host arrays to the device; tags ``sp`` with their
+    ``h2d_bytes``."""
+    import jax.numpy as jnp
+    sp.set(h2d_bytes=sum(a.nbytes for a in arrays))
+    return [jnp.asarray(a) for a in arrays]
+
+
 class _EpochView:
     """One epoch's immutable device-side state — the read buffer of the
     double-buffered swap.
@@ -846,8 +853,8 @@ class DeviceEngine(QueryEngine):
         """Full (re)build of every derived device structure for a fresh
         materialized/rebuilt snapshot.  Called at construction and per
         committed rebuild refresh; patch refreshes take ``_patch_install``
-        (O(|Δ|)) instead."""
-        import jax.numpy as jnp
+        (O(|Δ|)) instead.  Host arrays are built first, then uploaded
+        together under ``device.refresh.upload``."""
         from ..kernels.ops import pad_keys
         st = _EpochView()
         st.wiki = wiki
@@ -856,14 +863,12 @@ class DeviceEngine(QueryEngine):
         # pad the digest view once so the Pallas kernel path is eligible
         khi_v, klo_v, view_rows = wiki.search_view()
         khi, klo = pad_keys(np.asarray(khi_v), np.asarray(klo_v))
-        st.khi = jnp.asarray(khi)
-        st.klo = jnp.asarray(klo)
         st.view_rows = np.asarray(view_rows)
         # explicit copy: jnp.asarray can zero-copy a host numpy array, and
         # the patch path mutates wiki.path_tokens in place — the epoch
         # view's device buffer must not alias the mutable master
-        st.ptoks = jnp.asarray(np.array(wiki.path_tokens))
-        st.pinned = self._stage_pinned(wiki, khi_v, klo_v)
+        ptoks = np.array(wiki.path_tokens)
+        pinned = self._pinned_host(wiki, khi_v, klo_v)
         self._max_path_bytes = int(wiki.path_tokens.shape[1])
         # device token-digest table: sorted FNV digests of every segment
         # token + CSR of matching path rows (rows pre-sorted by path bytes,
@@ -888,21 +893,22 @@ class DeviceEngine(QueryEngine):
         thi, tlo = pad_keys(
             (tdig >> np.uint64(32)).astype(np.uint32),
             (tdig & np.uint64(0xFFFFFFFF)).astype(np.uint32))
-        st.tok_hi = jnp.asarray(thi)
-        st.tok_lo = jnp.asarray(tlo)
         st.tok_offsets = t_off
         st.tok_rows = np.asarray(t_rows, dtype=np.int32)
         st.tok_patch = {}
         st.tok_extra = {}
+        with obs.span("device.refresh.upload") as up:
+            (st.khi, st.klo, st.ptoks, st.tok_hi, st.tok_lo,
+             *pin) = _upload(up, khi, klo, ptoks, thi, tlo, *pinned)
+            st.pinned = tuple(pin)
         self._st = st
         self.last_refresh_kind = wiki.refresh_kind
 
-    def _stage_pinned(self, wiki, khi_view: np.ndarray, klo_view: np.ndarray):
-        """Stage the pinned hot set ("/" + every dimension — the paper's
-        L1 tier) for the kernel's VMEM level-0 probe: (hi, lo, position)
-        where position is the row's rank in the sorted search view — the
-        value the HBM binary search would produce."""
-        import jax.numpy as jnp
+    def _pinned_host(self, wiki, khi_view: np.ndarray, klo_view: np.ndarray):
+        """The pinned hot set ("/" + every dimension — the paper's L1
+        tier), as host arrays for the kernel's VMEM level-0 probe:
+        (hi, lo, position) where position is the row's rank in the sorted
+        search view — the value the HBM binary search would produce."""
         from ..kernels.ops import pad_pinned
         prow = wiki.pinned_rows()
         phi = np.asarray(wiki.keys_hi[prow])
@@ -910,8 +916,7 @@ class DeviceEngine(QueryEngine):
         k64 = (khi_view.astype(np.uint64) << np.uint64(32)) | klo_view.astype(np.uint64)
         p64 = (phi.astype(np.uint64) << np.uint64(32)) | plo.astype(np.uint64)
         pos = np.searchsorted(k64, p64).astype(np.int32)
-        phi_p, plo_p, pos_p = pad_pinned(phi, plo, pos)
-        return (jnp.asarray(phi_p), jnp.asarray(plo_p), jnp.asarray(pos_p))
+        return pad_pinned(phi, plo, pos)
 
     def _patch_install(self, prev: _EpochView, wiki,
                        records: list[Optional[R.Record]], info) -> None:
@@ -921,31 +926,34 @@ class DeviceEngine(QueryEngine):
         written — jax ``.at[].set`` allocates the successor, and overlay
         dicts are copied — so readers holding e keep a consistent view
         through the swap."""
-        import jax.numpy as jnp
         from ..kernels.ops import pad_keys
         st = _EpochView()
         st.wiki = wiki
         st.records = records
         st.paths = wiki.paths
+        st.khi, st.klo = prev.khi, prev.klo
+        st.view_rows = prev.view_rows
+        st.pinned = prev.pinned
+        st.ptoks = prev.ptoks
+        keys = ()
         if info.keys_changed:
             khi_v, klo_v, view_rows = wiki.search_view()
             khi, klo = pad_keys(np.asarray(khi_v), np.asarray(klo_v))
-            st.khi = jnp.asarray(khi)
-            st.klo = jnp.asarray(klo)
             st.view_rows = np.asarray(view_rows)
             # any membership change shifts sorted-view ranks → restage
-            st.pinned = self._stage_pinned(wiki, khi_v, klo_v)
-        else:
-            st.khi, st.klo = prev.khi, prev.klo
-            st.view_rows = prev.view_rows
-            st.pinned = prev.pinned
+            keys = (khi, klo, *self._pinned_host(wiki, khi_v, klo_v))
         touched = list(info.new_rows) + list(info.removed_rows)
+        rows = ()
         if touched:
             idx = np.asarray(touched, dtype=np.int32)
-            st.ptoks = prev.ptoks.at[jnp.asarray(idx)].set(
-                jnp.asarray(wiki.path_tokens[idx]))
-        else:
-            st.ptoks = prev.ptoks
+            rows = (idx, wiki.path_tokens[idx])
+        with obs.span("device.refresh.upload") as up:
+            dev = _upload(up, *keys, *rows)
+            if keys:
+                st.khi, st.klo, *pin = dev[:len(keys)]
+                st.pinned = tuple(pin)
+            if rows:
+                st.ptoks = prev.ptoks.at[dev[-2]].set(dev[-1])
         # token table: the packed base (digests + CSR) is immutable; rows
         # of changed tokens move to copy-on-write overlays, folded back
         # into the base at the next rebuild.  The engine-level master map
@@ -1060,18 +1068,14 @@ class DeviceEngine(QueryEngine):
             delta = TS.TensorDelta(epoch=self.epoch + 1,
                                    upserts=upserts, unlinks=unlinks)
             prev = self._st
-            t_apply = time.perf_counter() if obs.enabled() else 0.0
-            with obs.span("device.refresh.apply", rows=len(delta)):
+            with obs.span("device.refresh.apply", rows=len(delta)) as ap:
                 wiki, recs, info = TS.apply_delta_ex(
                     self.wiki, self.records, delta, mode=self.refresh_mode)
                 if info.kind == "patch":
                     self._patch_install(prev, wiki, recs, info)
                 else:
                     self._install(wiki, recs)
-            if t_apply:
-                # patch-vs-rebuild cost curves, separately addressable
-                obs.histogram(f"device.refresh.{info.kind}").record(
-                    (time.perf_counter() - t_apply) * 1e3)
+                ap.set(kind=info.kind)
             sp.set(kind=info.kind, epoch=self.epoch + 1)
             self.delta_log.append(delta)
             del self.delta_log[:-self.DELTA_LOG_KEEP]
@@ -1097,42 +1101,47 @@ class DeviceEngine(QueryEngine):
             p <<= 1
         return p
 
-    def _lookup_rows(self, st: _EpochView, digest_pairs: np.ndarray,
-                     table=None) -> np.ndarray:
-        """One batched device lookup: (Q, 2) uint64 pairs → (Q,) row ids.
-        Main-table lookups (table=None) probe the epoch's pinned VMEM
-        sub-table first, then map sorted-view positions back to stable
-        row ids through ``view_rows``."""
-        import jax.numpy as jnp
-        from ..kernels.ops import path_lookup
+    def _stage_lookup(self, digest_pairs: np.ndarray, sp) -> tuple:
+        """Pad (Q, 2) uint64 digest pairs to a power-of-two bucket and
+        upload them: (Q, hi, lo), or (0, None, None) for an empty batch."""
         q = digest_pairs.shape[0]
         if q == 0:
-            return np.zeros((0,), dtype=np.int32)
-        if table is None:
-            khi, klo, pinned = st.khi, st.klo, st.pinned
-        else:
-            (khi, klo), pinned = table, None
+            return 0, None, None
         qp = self._pad_pow2(q)
         if qp != q:
             # (0, 0) can never collide with an FNV digest of a non-empty
             # path; the padded tail is sliced off regardless
             pad = np.zeros((qp - q, 2), dtype=np.uint64)
             digest_pairs = np.concatenate([digest_pairs, pad])
-        rows = path_lookup(
-            khi, klo,
-            jnp.asarray(digest_pairs[:, 0].astype(np.uint32)),
-            jnp.asarray(digest_pairs[:, 1].astype(np.uint32)),
-            pinned=pinned)
-        rows = np.asarray(rows)[:q]
+        return (q, *_upload(sp, digest_pairs[:, 0].astype(np.uint32),
+                            digest_pairs[:, 1].astype(np.uint32)))
+
+    @staticmethod
+    def _lookup(st: _EpochView, staged: tuple, sp, table=None) -> np.ndarray:
+        """One batched device lookup of staged queries, through the copy
+        of its result back to the host: (Q,) sorted-view positions (main
+        table) or token rows (``table``); tags ``sp`` with ``d2h_bytes``.
+        Main-table lookups probe the epoch's pinned VMEM sub-table first."""
+        from ..kernels.ops import path_lookup
+        q, q_hi, q_lo = staged
+        if q == 0:
+            return np.zeros((0,), dtype=np.int32)
         if table is None:
-            # sorted-view position → row id, clipped against the padded
-            # key-table tail
-            n_view = len(st.view_rows)
-            valid = (rows >= 0) & (rows < n_view)
-            safe = np.clip(rows, 0, max(n_view - 1, 0))
-            return np.where(valid, st.view_rows[safe], -1).astype(np.int32)
-        n_rows = len(st.tok_offsets) - 1
-        return np.where(rows >= n_rows, -1, rows)
+            khi, klo, pinned = st.khi, st.klo, st.pinned
+        else:
+            (khi, klo), pinned = table, None
+        rows = np.asarray(path_lookup(khi, klo, q_hi, q_lo, pinned=pinned))
+        sp.set(d2h_bytes=rows.nbytes)
+        return rows[:q]
+
+    @staticmethod
+    def _view_rows(st: _EpochView, pos: np.ndarray) -> np.ndarray:
+        """Sorted-view positions → stable row ids, −1 for misses and the
+        padded key-table tail."""
+        n_view = len(st.view_rows)
+        valid = (pos >= 0) & (pos < n_view)
+        safe = np.clip(pos, 0, max(n_view - 1, 0))
+        return np.where(valid, st.view_rows[safe], -1).astype(np.int32)
 
     def _digests(self, paths: list[str]) -> np.ndarray:
         out = np.zeros((len(paths), 2), dtype=np.uint64)
@@ -1145,13 +1154,22 @@ class DeviceEngine(QueryEngine):
         return [P.normalize(p, depth_budget=self.depth_budget) for p in paths]
 
     # ------------------------------------------------------------------
+    # Each batched read runs in three child spans, tagged with its op:
+    # device.prep (normalize, hash, pack, pad, upload; h2d_bytes),
+    # device.wait (kernel dispatch until its result is on the host;
+    # d2h_bytes) and device.map (row ids to records or paths, limits).
     def q1_get(self, paths):
         self.stats.record(Q1, len(paths))
         with obs.span("device.q1_get"):
             st = self._st
-            norm = self._norm(paths)
-            rows = self._lookup_rows(st, self._digests(norm))
-            return [st.records[r] if r >= 0 else None for r in rows]
+            with obs.span("device.prep", op=Q1) as sp:
+                norm = self._norm(paths)
+                staged = self._stage_lookup(self._digests(norm), sp)
+            with obs.span("device.wait", op=Q1) as sp:
+                pos = self._lookup(st, staged, sp)
+            with obs.span("device.map", op=Q1):
+                rows = self._view_rows(st, pos)
+                return [st.records[r] if r >= 0 else None for r in rows]
 
     def q2_ls(self, paths):
         """One batched lookup; children come co-located in the resolved
@@ -1162,16 +1180,20 @@ class DeviceEngine(QueryEngine):
         self.stats.record(Q2, len(paths))
         with obs.span("device.q2_ls"):
             st = self._st
-            norm = self._norm(paths)
-            rows = self._lookup_rows(st, self._digests(norm))
-            out = []
-            for p, r in zip(norm, rows):
-                rec = st.records[r] if r >= 0 else None
-                if rec is None or not isinstance(rec, R.DirRecord):
-                    out.append(None)
-                    continue
-                out.append((rec, [P.child(p, s) for s in rec.children()]))
-            return out
+            with obs.span("device.prep", op=Q2) as sp:
+                norm = self._norm(paths)
+                staged = self._stage_lookup(self._digests(norm), sp)
+            with obs.span("device.wait", op=Q2) as sp:
+                pos = self._lookup(st, staged, sp)
+            with obs.span("device.map", op=Q2):
+                out = []
+                for p, r in zip(norm, self._view_rows(st, pos)):
+                    rec = st.records[r] if r >= 0 else None
+                    if rec is None or not isinstance(rec, R.DirRecord):
+                        out.append(None)
+                        continue
+                    out.append((rec, [P.child(p, s) for s in rec.children()]))
+                return out
 
     def q3_navigate(self, paths):
         """The whole batch's ancestor chains flatten into ONE lookup
@@ -1179,15 +1201,18 @@ class DeviceEngine(QueryEngine):
         self.stats.record(Q3, len(paths))
         with obs.span("device.q3_navigate"):
             st = self._st
-            norm = self._norm(paths)
-            chains = [list(P.ancestors(p)) + [p] for p in norm]
-            flat = [a for chain in chains for a in chain]
-            rows = self._lookup_rows(st, self._digests(flat))
+            with obs.span("device.prep", op=Q3) as sp:
+                chains = [list(P.ancestors(p)) + [p] for p in self._norm(paths)]
+                flat = [a for chain in chains for a in chain]
+                staged = self._stage_lookup(self._digests(flat), sp)
+            with obs.span("device.wait", op=Q3) as sp:
+                pos = self._lookup(st, staged, sp)
             # the flat lookup resolves every level even past a miss (the
             # batch is issued before results are known); the per-path
             # result still truncates at the first miss, matching
             # PathStore.navigate
-            return self._q3_truncate(st, chains, rows)
+            with obs.span("device.map", op=Q3):
+                return self._q3_truncate(st, chains, self._view_rows(st, pos))
 
     @staticmethod
     def _q3_truncate(st: _EpochView, chains, rows) -> list[list[R.Record]]:
@@ -1222,46 +1247,49 @@ class DeviceEngine(QueryEngine):
             return self._q4_search(prefixes, limit)
 
     def _q4_search(self, prefixes, limit):
-        import jax.numpy as jnp
         from . import tensorstore as TS
         from ..kernels.ops import prefix_search
         st = self._st
-        fixed = [p if p.startswith(P.SEP) else P.SEP + p for p in prefixes]
-        L = self._max_path_bytes
-        qp = self._pad_pow2(len(fixed), floor=4)
-        # pad with unmatchable prefixes (0xFF never occurs in a path) so
-        # the jitted scan sees bucketed shapes
-        pref_mat = np.full((qp, L), 255, dtype=np.uint8)
-        lens = np.full((qp,), 1, dtype=np.int32)
-        long_idx: set[int] = set()
-        for i, p in enumerate(fixed):
-            blen = len(p.encode("utf-8"))
-            if blen >= L:
-                # the packed token matrix truncates at L bytes, so the
-                # kernel cannot decide these exactly — resolve them from
-                # the untruncated host-side path list instead (rare: the
-                # depth budget keeps normal prefixes far below L)
-                long_idx.add(i)
-            else:
-                pref_mat[i] = TS.pack_path(p, L)
-                lens[i] = blen
-        bitmap = np.asarray(prefix_search(
-            st.ptoks, jnp.asarray(pref_mat), jnp.asarray(lens)))
-        n_paths = len(st.paths)
-        out: list[list[str]] = []
-        for qi in range(len(fixed)):
-            if qi in long_idx:
-                seg_pref = fixed[qi].rstrip(P.SEP) or P.ROOT
-                matches = sorted(
-                    p for p in st.wiki.row_of
-                    if p.startswith(fixed[qi])
-                    and (P.is_prefix(seg_pref, p) or p == fixed[qi]))
+        with obs.span("device.prep", op=Q4) as sp:
+            fixed = [p if p.startswith(P.SEP) else P.SEP + p for p in prefixes]
+            L = self._max_path_bytes
+            qp = self._pad_pow2(len(fixed), floor=4)
+            # pad with unmatchable prefixes (0xFF never occurs in a path) so
+            # the jitted scan sees bucketed shapes
+            pref_mat = np.full((qp, L), 255, dtype=np.uint8)
+            lens = np.full((qp,), 1, dtype=np.int32)
+            long_idx: set[int] = set()
+            for i, p in enumerate(fixed):
+                blen = len(p.encode("utf-8"))
+                if blen >= L:
+                    # the packed token matrix truncates at L bytes, so the
+                    # kernel cannot decide these exactly — resolve them from
+                    # the untruncated host-side path list instead (rare: the
+                    # depth budget keeps normal prefixes far below L)
+                    long_idx.add(i)
+                else:
+                    pref_mat[i] = TS.pack_path(p, L)
+                    lens[i] = blen
+            pref_dev, lens_dev = _upload(sp, pref_mat, lens)
+        with obs.span("device.wait", op=Q4) as sp:
+            bitmap = np.asarray(prefix_search(st.ptoks, pref_dev, lens_dev))
+            sp.set(d2h_bytes=bitmap.nbytes)
+        with obs.span("device.map", op=Q4):
+            n_paths = len(st.paths)
+            out: list[list[str]] = []
+            for qi in range(len(fixed)):
+                if qi in long_idx:
+                    seg_pref = fixed[qi].rstrip(P.SEP) or P.ROOT
+                    matches = sorted(
+                        p for p in st.wiki.row_of
+                        if p.startswith(fixed[qi])
+                        and (P.is_prefix(seg_pref, p) or p == fixed[qi]))
+                    out.append(matches if limit is None else matches[:limit])
+                    continue
+                hits = np.nonzero(bitmap[qi, :n_paths])[0]
+                matches = sorted(st.paths[r] for r in hits)
                 out.append(matches if limit is None else matches[:limit])
-                continue
-            hits = np.nonzero(bitmap[qi, :n_paths])[0]
-            matches = sorted(st.paths[r] for r in hits)
-            out.append(matches if limit is None else matches[:limit])
-        return out
+            return out
 
     def q4_contains(self, tokens, limit=None):
         """Keyword routing: the segment-token inverted index as a device
@@ -1274,29 +1302,34 @@ class DeviceEngine(QueryEngine):
             return []
         with obs.span("device.q4_contains"):
             st = self._st
-            norm_toks = [t.lower() for t in tokens]
-            dig = np.zeros((len(norm_toks), 2), dtype=np.uint64)
-            for i, t in enumerate(norm_toks):
-                h = _token_hash(t)
-                dig[i] = ((h >> 32) & 0xFFFFFFFF, h & 0xFFFFFFFF)
-            rows = self._lookup_rows(st, dig, table=(st.tok_hi, st.tok_lo))
-            out: list[list[str]] = []
-            for t, r in zip(norm_toks, rows):
-                if r >= 0:
-                    over = st.tok_patch.get(int(r))
-                    if over is not None:
-                        prows = over
+            with obs.span("device.prep", op=Q4C) as sp:
+                norm_toks = [t.lower() for t in tokens]
+                dig = np.zeros((len(norm_toks), 2), dtype=np.uint64)
+                for i, t in enumerate(norm_toks):
+                    h = _token_hash(t)
+                    dig[i] = ((h >> 32) & 0xFFFFFFFF, h & 0xFFFFFFFF)
+                staged = self._stage_lookup(dig, sp)
+            with obs.span("device.wait", op=Q4C) as sp:
+                rows = self._lookup(st, staged, sp, table=(st.tok_hi, st.tok_lo))
+            with obs.span("device.map", op=Q4C):
+                n_rows = len(st.tok_offsets) - 1
+                out: list[list[str]] = []
+                for t, r in zip(norm_toks, rows):
+                    if 0 <= r < n_rows:
+                        over = st.tok_patch.get(int(r))
+                        if over is not None:
+                            prows = over
+                        else:
+                            lo, hi = st.tok_offsets[r], st.tok_offsets[r + 1]
+                            prows = st.tok_rows[lo:hi]
                     else:
-                        lo, hi = st.tok_offsets[r], st.tok_offsets[r + 1]
-                        prows = st.tok_rows[lo:hi]
-                else:
-                    # token absent from the packed table — it may have
-                    # been introduced by a patch refresh since the last
-                    # rebuild
-                    prows = st.tok_extra.get(t, ())
-                matches = [st.paths[i] for i in prows]
-                out.append(matches if limit is None else matches[:limit])
-            return out
+                        # token absent from the packed table — it may have
+                        # been introduced by a patch refresh since the last
+                        # rebuild
+                        prows = st.tok_extra.get(t, ())
+                    matches = [st.paths[i] for i in prows]
+                    out.append(matches if limit is None else matches[:limit])
+                return out
 
 
 # ---------------------------------------------------------------------------
@@ -1412,7 +1445,6 @@ class BatchPlanner:
                      for futs in by_key.values()) + len(writes))
         self.flushes += 1
         obs.set_context(wave=self.flushes)
-        obs.gauge("planner.queue_depth").set(depth)
         resolved = 0
         with obs.span("planner.flush", depth=depth,
                       writes=len(writes)) as sp:

@@ -199,6 +199,7 @@ def path_lookup(keys_hi: jax.Array, keys_lo: jax.Array,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((Qp, 1), jnp.int32),
         interpret=interpret,
+        name="path_lookup_route",
     )(qhi, qlo, fhi, flo, phi, plo, ppos)
 
     blk = pl.BlockSpec((bq, 1), lambda qb, code_s: (qb, 0))
@@ -216,6 +217,7 @@ def path_lookup(keys_hi: jax.Array, keys_lo: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct((Qp, 1), jnp.int32),
         interpret=interpret,
+        name="path_lookup_probe",
     )(code.reshape(Qp), khi, klo, qhi, qlo, code)
     return out[:Q, 0]
 

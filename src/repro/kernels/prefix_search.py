@@ -121,5 +121,6 @@ def prefix_search(tokens: jax.Array, prefixes: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct((Q, n_rows, 128), jnp.int8),
         interpret=interpret,
+        name="prefix_search_scan",
     )(meta, tw)
     return out.reshape(Q, Np)[:, :N].astype(jnp.bool_)

@@ -12,6 +12,10 @@ Switched by ``REPRO_TRACE`` (default ``0``): when disabled, ``span()``
 returns a no-op singleton and the metric accessors return a shared null
 metric — no clock reads, no dict churn, zero allocations on every hot
 path (the bench gate runs with tracing off and must see no regression).
+An enabled registry also records every pass of Python's cyclic collector
+as a ``gc.collect`` span (one ``gc.callbacks`` hook, removed again when
+``configure()`` replaces the registry), and every span reaches a running
+``jax.profiler`` capture as a ``TraceAnnotation`` (see ``trace.py``).
 ``configure(enabled=True)`` flips the live registry at runtime (tests,
 ``examples/quickstart.py``); ``REPRO_STATS_EVERY`` and
 ``REPRO_TRACE_RING`` tune the serving stats-log cadence and the ring
@@ -20,6 +24,7 @@ schema).
 """
 from __future__ import annotations
 
+import gc
 import os
 import time
 from collections import deque
@@ -65,6 +70,27 @@ class Registry:
         self.ctx: dict[str, object] = {}
         self.t0 = time.perf_counter()
         self.pid = os.getpid()
+        self._gc_span: Span | None = None
+        if self.enabled:
+            gc.callbacks.append(self._on_gc)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        """``gc.callbacks`` hook: one collector pass, start to stop, as a
+        ``gc.collect`` span.  Passes never overlap (the interpreter runs
+        one at a time), so one open span suffices."""
+        if phase == "start":
+            self._gc_span = Span(self, "gc.collect",
+                                 {"generation": info["generation"]})
+            self._gc_span.__enter__()
+        elif self._gc_span is not None:
+            sp, self._gc_span = self._gc_span, None
+            sp.__exit__(None, None, None)
+
+    def close(self) -> None:
+        """Remove the collector hook; ``configure`` closes the registry it
+        replaces."""
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
 
     # -- metric accessors (null objects when disabled) ----------------------
     # setdefault is a single atomic dict op, so two worker threads racing
@@ -146,6 +172,7 @@ def configure(enabled: bool | None = None,
     """Replace the global registry (runtime enable/disable for tests and
     examples); returns the new registry."""
     global _registry
+    _registry.close()
     _registry = Registry(enabled=enabled, ring_size=ring_size)
     return _registry
 

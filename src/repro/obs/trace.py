@@ -18,27 +18,44 @@ two events on a ``tid`` are either disjoint or properly contained —
 monotonicity, and ``scripts/check_trace.py`` runs it in CI against the
 trace the smoke serving wave exports.
 
+Profiler bridge: an enabled span also enters ``jax.profiler.TraceAnnotation``
+of its name around its region, once ``jax.profiler`` is imported (nothing
+else can be recording a profile), so a ``jax.profiler`` capture holds the
+program's spans on the same host clock as the device's program launches.
+``obs`` never imports JAX itself.
+
 When tracing is disabled (``REPRO_TRACE=0``, the default) ``span()``
 returns the :data:`NULL_SPAN` singleton: enter/exit are no-ops, nothing
-is timed, nothing is allocated.
+is timed, nothing is allocated, no annotation is made.
 """
 from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
+from contextlib import nullcontext
 
 # Chrome trace-event keys — see the Trace Event Format spec (Perfetto
 # loads this JSON directly)
 _PH_COMPLETE = "X"
+
+_NO_ANNOTATION = nullcontext()
+
+
+def _annotation(name: str):
+    """The profiler's ``TraceAnnotation`` of ``name``; a no-op context
+    while ``jax.profiler`` is not imported."""
+    prof = sys.modules.get("jax.profiler")
+    return _NO_ANNOTATION if prof is None else prof.TraceAnnotation(name)
 
 
 class Span:
     """One timed region; use as a context manager.  ``set(**tags)`` adds
     args after entry (e.g. a result kind known only at the end)."""
 
-    __slots__ = ("_reg", "name", "args", "_t0", "dur_ms")
+    __slots__ = ("_reg", "name", "args", "_t0", "dur_ms", "_ann")
 
     def __init__(self, reg, name: str, args: dict | None):
         self._reg = reg
@@ -55,11 +72,14 @@ class Span:
         return self
 
     def __enter__(self) -> "Span":
+        self._ann = _annotation(self.name)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         t1 = time.perf_counter()
+        self._ann.__exit__(exc_type, exc, tb)
         self.dur_ms = (t1 - self._t0) * 1e3
         reg = self._reg
         args = dict(reg.ctx)
@@ -79,8 +99,8 @@ class Span:
 
 
 class _NullSpan:
-    """The disabled-mode singleton: no clock reads, no ring append, no
-    histogram, no allocations."""
+    """The disabled-mode singleton: no clock reads, no annotation, no ring
+    append, no histogram, no allocations."""
 
     __slots__ = ()
     name = ""

@@ -2,7 +2,8 @@
 
 Each test lowers one Pallas kernel with ``interpret=False`` against a
 *described* v5e chip (no chip attached — the TPU compiler runs here) and
-asserts the compiled program holds the Mosaic ``tpu_custom_call``.  What
+asserts the compiled program holds the Mosaic ``tpu_custom_call``, under
+the kernel's own name where the storage kernels give one.  What
 the chip's compiler refuses (unsupported primitives, misaligned blocks,
 scoped-VMEM overflow) fails here instead of on the chip.
 
@@ -61,6 +62,8 @@ def test_path_lookup_compiles_at_2pow20(one_chip):
         s((n,), u32), s((n,), u32), s((q,), u32), s((q,), u32),
         s((n_pin,), u32), s((n_pin,), u32), s((n_pin,), jnp.int32))
     assert "tpu_custom_call" in txt
+    # the kernels' names become their op names on a trace's XLA Ops line
+    assert "%path_lookup_route" in txt and "%path_lookup_probe" in txt
 
 
 def test_prefix_search_compiles_at_2pow20(one_chip):
@@ -74,6 +77,7 @@ def test_prefix_search_compiles_at_2pow20(one_chip):
         s((n, width), jnp.uint8), s((q, width), jnp.uint8),
         s((q,), jnp.int32))
     assert "tpu_custom_call" in txt
+    assert "%prefix_search_scan" in txt
 
 
 def test_decode_attention_compiles_at_router_serving_shapes(one_chip):
