@@ -749,11 +749,20 @@ class _EpochView:
     field of an installed view is ever written again (patch refreshes
     build the successor with jax functional updates / fresh overlay
     dicts, never in-place writes to the previous view's buffers).
+
+    The one exception is ``scan_tab``, None until the epoch's first Q4
+    prefix search fills it: a memo of the prefix scan's packed table, a
+    pure function of the immutable ``ptoks``.  Two concurrent first
+    searches may both build it; the builds are identical, so either
+    assignment is correct.  It is freed with its view.
     """
 
     __slots__ = ("wiki", "records", "paths", "khi", "klo", "view_rows",
                  "ptoks", "pinned", "tok_hi", "tok_lo", "tok_offsets",
-                 "tok_rows", "tok_patch", "tok_extra")
+                 "tok_rows", "tok_patch", "tok_extra", "scan_tab")
+
+    def __init__(self):
+        self.scan_tab = None
 
 
 class DeviceEngine(QueryEngine):
@@ -761,7 +770,8 @@ class DeviceEngine(QueryEngine):
 
     Q1/Q3/keyword routing run through ``kernels.ops.path_lookup`` (Pallas
     on TPU, binary-search reference elsewhere); Q4 prefix scans run
-    through ``kernels.ops.prefix_search``.  Record payloads are resolved
+    through ``kernels.ops.prefix_search_packed`` over a table packed once
+    per epoch.  Record payloads are resolved
     from a host-side row table — the row id IS the payload pointer, so the
     device op does all the addressing work.
 
@@ -1239,17 +1249,24 @@ class DeviceEngine(QueryEngine):
         pending prefix is compared against each resident path tile.  The
         scan runs over the row-order token matrix (free slots are zeros,
         tombstones 255s — neither can match a real prefix), so a patch
-        refresh only re-uploads the touched rows."""
+        refresh only re-uploads the touched rows; the scan's packed
+        layout of it is built by the epoch's first search and reused by
+        the rest (``_EpochView.scan_tab``)."""
+        from ..kernels.ops import prefix_scan_table
         self.stats.record(Q4, len(prefixes))
         if not prefixes:
             return []
-        with obs.span("device.q4_search"):
-            return self._q4_search(prefixes, limit)
-
-    def _q4_search(self, prefixes, limit):
-        from . import tensorstore as TS
-        from ..kernels.ops import prefix_search
         st = self._st
+        if st.scan_tab is None:
+            # beside device.q4_search, whose children stay its three stages
+            with obs.span("device.q4_pack", scan_packs=1):
+                st.scan_tab = prefix_scan_table(st.ptoks)
+        with obs.span("device.q4_search"):
+            return self._q4_search(st, prefixes, limit)
+
+    def _q4_search(self, st: _EpochView, prefixes, limit):
+        from . import tensorstore as TS
+        from ..kernels.ops import prefix_search_packed
         with obs.span("device.prep", op=Q4) as sp:
             fixed = [p if p.startswith(P.SEP) else P.SEP + p for p in prefixes]
             L = self._max_path_bytes
@@ -1272,7 +1289,8 @@ class DeviceEngine(QueryEngine):
                     lens[i] = blen
             pref_dev, lens_dev = _upload(sp, pref_mat, lens)
         with obs.span("device.wait", op=Q4) as sp:
-            bitmap = np.asarray(prefix_search(st.ptoks, pref_dev, lens_dev))
+            bitmap = np.asarray(prefix_search_packed(
+                st.scan_tab, st.ptoks.shape[0], pref_dev, lens_dev))
             sp.set(d2h_bytes=bitmap.nbytes)
         with obs.span("device.map", op=Q4):
             n_paths = len(st.paths)

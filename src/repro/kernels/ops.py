@@ -22,7 +22,9 @@ from .decode_attention import decode_attention as _decode_pallas
 from .flash_attention import flash_attention as _flash_pallas
 from .moe_router import moe_router as _router_pallas
 from .path_lookup import pad_keys, pad_pinned, path_lookup as _lookup_pallas
+from .prefix_search import pack_scan_table as _pack_scan_pallas
 from .prefix_search import prefix_search as _prefix_pallas
+from .prefix_search import prefix_search_packed as _prefix_packed_pallas
 from .rmsnorm import rmsnorm as _rmsnorm_pallas
 
 
@@ -113,6 +115,25 @@ def prefix_search(tokens, prefixes, prefix_lens, *, block_n: int = 4096):
     return _prefix_ref_batched(tokens, prefixes, prefix_lens)
 
 
+def prefix_scan_table(tokens):
+    """(N, L) token matrix → the table ``prefix_search_packed`` scans: the
+    kernel's packed, path-minor words, or on the reference path the
+    tokens as they are.  A pure function of ``tokens``, so a caller
+    builds it once per token matrix."""
+    if _use_pallas():
+        return _pack_scan_pallas(tokens)
+    return tokens
+
+
+def prefix_search_packed(table, n: int, prefixes, prefix_lens):
+    """``prefix_scan_table`` of an (n, L) token matrix × (Q, L) prefixes →
+    (Q, n) bitmap, the same answers as ``prefix_search`` on the tokens."""
+    if _use_pallas():
+        return _prefix_packed_pallas(table, n, prefixes, prefix_lens,
+                                     interpret=not _on_tpu())
+    return _prefix_ref_batched(table, prefixes, prefix_lens)
+
+
 @jax.jit
 def _prefix_ref_batched(tokens, prefixes, prefix_lens):
     return jax.vmap(lambda p, n: ref.prefix_search_ref(tokens, p, n))(
@@ -120,4 +141,5 @@ def _prefix_ref_batched(tokens, prefixes, prefix_lens):
 
 
 __all__ = ["attention", "decode_attention", "moe_router", "rmsnorm",
-           "path_lookup", "prefix_search", "pad_keys", "pad_pinned"]
+           "path_lookup", "prefix_search", "prefix_scan_table",
+           "prefix_search_packed", "pad_keys", "pad_pinned"]

@@ -6,15 +6,18 @@ against every pending prefix while resident, applies the segment-
 boundary rule ("/a" must not match "/ab"), and emits one match row per
 prefix.
 
-Layout: the wrapper packs each path's L bytes into L/4 little-endian
-int32 words and lays the words out path-minor, (L/4, N/128, 128), so one
-word of 1024 consecutive paths is a dense (8, 128) vreg tile and the
-path axis — never the small prefix batch — is the lane axis.  Per prefix
-the kernel XORs each word against the prefix word (an SMEM scalar),
-masks the bytes beyond the prefix length and ORs into a mismatch
-accumulator: 4 bytes compared per lane op, with only the ⌈len/4⌉ words
-the prefix covers visited.  The boundary byte is one dynamic word load
-plus a shift.  Output rows are int8 (Q, N) — lane-dense in N.
+Layout: ``pack_scan_table`` packs each path's L bytes into L/4
+little-endian int32 words and lays the words out path-minor,
+(L/4, N/128, 128), so one word of 1024 consecutive paths is a dense
+(8, 128) vreg tile and the path axis — never the small prefix batch — is
+the lane axis.  The table is packed once per token matrix;
+``prefix_search_packed`` scans it for any number of prefix batches.  Per
+prefix the kernel XORs each word against the prefix word (an SMEM
+scalar), masks the bytes beyond the prefix length and ORs into a
+mismatch accumulator: 4 bytes compared per lane op, with only the
+⌈len/4⌉ words the prefix covers visited.  The boundary byte is one
+dynamic word load plus a shift.  Output rows are int8 (Q, N) —
+lane-dense in N.
 
 This is the bandwidth-roofline member of the kernel set: for N = 10⁷
 paths × 96 B ≈ 1 GB, one pass is ~1.2 ms at 819 GB/s, amortized across
@@ -88,28 +91,46 @@ def _prefix_meta(prefixes: jax.Array, prefix_lens: jax.Array,
     return jnp.concatenate([words, masks, meta], axis=1).reshape(-1)
 
 
-@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
-def prefix_search(tokens: jax.Array, prefixes: jax.Array,
-                  prefix_lens: jax.Array, *, block_n: int = 4096,
-                  interpret: bool) -> jax.Array:
-    """tokens: (N, L) uint8; prefixes: (Q, L) uint8; prefix_lens: (Q,) int32.
-    Returns the (Q, N) bool match bitmap.  ``block_n`` must be a multiple
+def _block_rows(n_rows: int, block_n: int) -> int:
+    """128-path lane rows per grid step."""
+    return max(1, min(block_n // 128, n_rows))
+
+
+@functools.partial(jax.jit, static_argnames=("block_n",))
+def pack_scan_table(tokens: jax.Array, *, block_n: int = 4096) -> jax.Array:
+    """tokens: (N, L) uint8 → the scan's (W, n_rows, 128) int32 table:
+    L padded to W = ⌈L/4⌉ little-endian words, N padded to ``n_rows``
+    lane rows of 128 paths, a multiple of the ``block_n`` grid step.
+    Extra bytes are zero (beyond every prefix); extra paths are 255s
+    (they match no real prefix) and are sliced off by the search.  A
+    pure function of ``tokens``: build it once per table and hand it to
+    every ``prefix_search_packed`` over that table."""
+    N, L = tokens.shape
+    L4 = -(-L // 4) * 4
+    n_rows = -(-N // 128)
+    bs = _block_rows(n_rows, block_n)
+    n_rows = -(-n_rows // bs) * bs
+    toks = jnp.pad(tokens, ((0, 0), (0, L4 - L)))
+    toks = jnp.pad(toks, ((0, n_rows * 128 - N), (0, 0)), constant_values=255)
+    return _pack_words(toks).T.reshape(L4 // 4, n_rows, 128)
+
+
+@functools.partial(jax.jit, static_argnames=("n", "block_n", "interpret"))
+def prefix_search_packed(table: jax.Array, n: int, prefixes: jax.Array,
+                         prefix_lens: jax.Array, *, block_n: int = 4096,
+                         interpret: bool) -> jax.Array:
+    """table: ``pack_scan_table`` of an (n, L) token matrix packed with the
+    same ``block_n``; prefixes: (Q, L) uint8; prefix_lens: (Q,) int32.
+    Returns the (Q, n) bool match bitmap.  ``block_n`` must be a multiple
     of 4096 (32 int8 sublanes × 128 lanes) or cover the whole padded
     table."""
-    N, L = tokens.shape
-    Q = prefixes.shape[0]
-    L4 = -(-L // 4) * 4
-    W = L4 // 4
-    n_rows = -(-N // 128)                    # 128-path lane rows
-    bs = max(1, min(block_n // 128, n_rows))
-    n_rows = -(-n_rows // bs) * bs
-    Np = n_rows * 128
-    # padding: extra bytes are zero (beyond every prefix), extra paths are
-    # 255s (match no real prefix); both are sliced off or masked
-    toks = jnp.pad(tokens, ((0, 0), (0, L4 - L)))
-    toks = jnp.pad(toks, ((0, Np - N), (0, 0)), constant_values=255)
-    tw = _pack_words(toks).T.reshape(W, n_rows, 128)
-    meta = _prefix_meta(jnp.pad(prefixes, ((0, 0), (0, L4 - L))),
+    W, n_rows, _ = table.shape
+    Q, L = prefixes.shape
+    bs = _block_rows(n_rows, block_n)
+    if n_rows % bs or -(-L // 4) != W or n > n_rows * 128:
+        raise ValueError(f"table {table.shape} was not packed from ({n}, {L}) "
+                         f"paths with block_n={block_n}")
+    meta = _prefix_meta(jnp.pad(prefixes, ((0, 0), (0, 4 * W - L))),
                         prefix_lens, L)
     out = pl.pallas_call(
         functools.partial(_prefix_kernel, n_words=W),
@@ -122,5 +143,18 @@ def prefix_search(tokens: jax.Array, prefixes: jax.Array,
         out_shape=jax.ShapeDtypeStruct((Q, n_rows, 128), jnp.int8),
         interpret=interpret,
         name="prefix_search_scan",
-    )(meta, tw)
-    return out.reshape(Q, Np)[:, :N].astype(jnp.bool_)
+    )(meta, table)
+    return out.reshape(Q, n_rows * 128)[:, :n].astype(jnp.bool_)
+
+
+@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
+def prefix_search(tokens: jax.Array, prefixes: jax.Array,
+                  prefix_lens: jax.Array, *, block_n: int = 4096,
+                  interpret: bool) -> jax.Array:
+    """tokens: (N, L) uint8; prefixes: (Q, L) uint8; prefix_lens: (Q,) int32.
+    Returns the (Q, N) bool match bitmap: one pack and one scan, for a
+    table searched once."""
+    table = pack_scan_table(tokens, block_n=block_n)
+    return prefix_search_packed(table, tokens.shape[0], prefixes,
+                                prefix_lens, block_n=block_n,
+                                interpret=interpret)

@@ -677,6 +677,88 @@ def test_epoch_view_unaffected_by_patch_swap():
         dev._st = st_next
 
 
+def test_q4_scan_table_packed_once_per_epoch():
+    """The prefix scan's packed table is built by an epoch's first Q4
+    search and reused by the rest; a patch refresh's new epoch builds its
+    own, which holds the new path, while epoch e's view keeps its table
+    and its answers."""
+    from repro import obs
+
+    reg = obs.configure(enabled=True, ring_size=4096)
+    try:
+        store = _seed_store()
+        dev = DeviceEngine.from_store(store)
+        st_e = dev.epoch_view()
+        assert st_e.scan_tab is None
+        before = dev.q4_search(["/d0"])
+        tab_e = st_e.scan_tab
+        assert tab_e is not None
+        for limit in (1, 2, None):
+            assert dev.q4_search(["/d0", "/d1"], limit=limit) == [
+                before[0][:limit], dev.q4_search(["/d1"])[0][:limit]]
+        assert st_e.scan_tab is tab_e
+
+        def packs():
+            return sum(e["args"].get("scan_packs", 0) for e in reg.ring
+                       if e["name"] == "device.q4_pack")
+
+        assert packs() == 1
+        pl = BatchPlanner(dev)
+        pl.admit("/d0/fresh", R.FileRecord(name="fresh", text="new"))
+        pl.flush()
+        dev.refresh()
+        assert dev.last_refresh_kind == "patch"
+        st_next = dev.epoch_view()
+        assert st_next is not st_e and st_next.scan_tab is None
+        after = dev.q4_search(["/d0"])
+        assert after[0] == sorted(before[0] + ["/d0/fresh"])
+        assert dev.q4_search(["/d0"], limit=2) == [after[0][:2]]
+        assert packs() == 2
+        assert st_next.scan_tab is not tab_e and st_e.scan_tab is tab_e
+        # a reader holding epoch e still scans epoch e's table
+        dev._st = st_e
+        try:
+            assert dev.q4_search(["/d0"]) == before
+        finally:
+            dev._st = st_next
+        assert packs() == 2
+    finally:
+        obs.configure()
+
+
+def test_q4_concurrent_first_searches_of_an_epoch_agree():
+    """Readers racing to build an epoch's scan table may each build it;
+    every build is the same, so every reader gets the same answers."""
+    import sys
+    import threading
+
+    store = _seed_store(n_dims=3, n_leaves=4)
+    dev = DeviceEngine.from_store(store)
+    probes = ["/d0", "/d1", "/d2", "/", "/nope"]
+    want = HostEngine(store).q4_search(probes)
+    got, errors = [], []
+
+    def reader():
+        try:
+            got.append(dev.q4_search(probes))
+        except Exception as e:  # noqa: BLE001 - reported by the assert below
+            errors.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=reader) for _ in range(8)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads) and not errors
+    assert got == [want] * 8
+    assert dev.epoch_view().scan_tab is not None
+
+
 def test_refresh_cadence_batches_visibility():
     """refresh_cadence=3: writes stay invisible through the first two
     refresh requests and commit on the third — ONE epoch bump for the

@@ -10,7 +10,8 @@ from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.moe_router import moe_router
 from repro.kernels.path_lookup import pad_keys, path_lookup
-from repro.kernels.prefix_search import prefix_search
+from repro.kernels.prefix_search import (pack_scan_table, prefix_search,
+                                         prefix_search_packed)
 from repro.kernels.rmsnorm import rmsnorm
 
 KEY = jax.random.PRNGKey(0)
@@ -148,6 +149,32 @@ def test_prefix_search_sweep(N, L, Q, bn):
                                jnp.asarray(plens[i])) for i in range(Q)],
         axis=0)
     assert jnp.all(got == want)
+
+
+@pytest.mark.parametrize("N,L,Q,bn", [(100, 48, 3, 32), (513, 96, 5, 128),
+                                      (300, 50, 4, 128), (4100, 37, 2, 4096)])
+def test_prefix_search_packed_reuses_table(N, L, Q, bn):
+    """One packed table answers several prefix batches, each bit for bit
+    as the one-shot pack-and-scan of the same tokens."""
+    rs = np.random.RandomState(N + L)
+    alphabet = np.frombuffer(b"abcd/", np.uint8)
+    toks = alphabet[rs.randint(0, 5, size=(N, L))].astype(np.uint8)
+    toks[:, 0] = ord("/")
+    toks = jnp.asarray(toks)
+    table = pack_scan_table(toks, block_n=bn)
+    for _ in range(3):
+        prefs = alphabet[rs.randint(0, 5, size=(Q, L))].astype(np.uint8)
+        prefs[:, 0] = ord("/")
+        plens = jnp.asarray(rs.randint(1, min(L, 10), size=Q).astype(np.int32))
+        got = prefix_search_packed(table, N, jnp.asarray(prefs), plens,
+                                   block_n=bn, interpret=True)
+        want = prefix_search(toks, jnp.asarray(prefs), plens, block_n=bn,
+                             interpret=True)
+        oracle = jax.vmap(ref.prefix_search_ref, in_axes=(None, 0, 0))(
+            toks, jnp.asarray(prefs), plens)
+        assert got.shape == (Q, N) and got.dtype == jnp.bool_
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+        assert np.array_equal(np.asarray(got), np.asarray(oracle))
 
 
 @pytest.mark.parametrize("N,Q,n_pin,bq", [(1000, 301, 5, 128),

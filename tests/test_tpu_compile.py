@@ -21,7 +21,8 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.path_lookup import PIN_TILE, path_lookup
-from repro.kernels.prefix_search import prefix_search
+from repro.kernels.prefix_search import (pack_scan_table, prefix_search,
+                                         prefix_search_packed)
 
 
 @pytest.fixture(scope="module")
@@ -76,6 +77,25 @@ def test_prefix_search_compiles_at_2pow20(one_chip):
         lambda t, p, ln: prefix_search(t, p, ln, interpret=False),
         s((n, width), jnp.uint8), s((q, width), jnp.uint8),
         s((q,), jnp.int32))
+    assert "tpu_custom_call" in txt
+    assert "%prefix_search_scan" in txt
+
+
+def test_packed_prefix_search_compiles_at_2pow20(one_chip):
+    """The engine's two programs, each on its own: the per-epoch pack of
+    the path table and the per-call scan of the packed table."""
+    n, width, q = 1 << 20, 96, 8
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    lowered = pack_scan_table.lower(s((n, width), jnp.uint8))
+    table = lowered.out_info
+    assert (table.shape, table.dtype) == ((width // 4, n // 128, 128), jnp.int32)
+    lowered.compile()
+    txt = prefix_search_packed.lower(
+        s(table.shape, jnp.int32), n, s((q, width), jnp.uint8),
+        s((q,), jnp.int32), interpret=False).compile().as_text()
     assert "tpu_custom_call" in txt
     assert "%prefix_search_scan" in txt
 
