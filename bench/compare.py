@@ -153,11 +153,41 @@ def _short(x, n: int = 160) -> str:
     return s if len(s) <= n else s[:n] + "..."
 
 
-def check(items, waves) -> dict[str, Tally]:
+def replay(items, waves) -> Checker:
     """Replay ``waves`` (objects with ``phase`` and ``entries``) over the
-    reference built from the initial ``items``; a tally per phase."""
+    reference built from the initial ``items``: the checker holds a tally
+    per phase, and the reference at the state after the last wave."""
     tokens = {e[1].lower() for w in waves for e in w.entries if e[0] == "q4_contains"}
     checker = Checker(items, tokens)
     for w in waves:
         checker.wave(w.phase, w.entries)
-    return checker.tallies
+    return checker
+
+
+def written_paths(waves) -> list[str]:
+    """Every path the writes of ``waves`` wrote or unlinked, then every
+    ancestor directory of them (an admission links its parent and creates
+    missing ancestors; an unlink rewrites its parent), each once."""
+    paths = list(dict.fromkeys(e[1] for w in waves for e in w.entries if e[0] in WRITES))
+    seen = set(paths)
+    dirs = dict.fromkeys(a for p in paths for a in _ancestors(p))
+    return paths + [d for d in dirs if d not in seen]
+
+
+def _ancestors(path: str) -> list[str]:
+    out = []
+    while path != refkv.ROOT:
+        path = refkv.parent(path)
+        out.append(path)
+    return out
+
+
+def durable(checker: Checker, get, paths: list[str]) -> None:
+    """Each path's record as ``get`` reads it from a reopened store against
+    the reference's after the last wave: the checker's ``durable`` tally."""
+    t = checker.tallies["durable"] = Tally()
+    for p in paths:
+        t.reads += 1
+        got, want = canon(get(p)), checker.ref.get(p)
+        if got != want:
+            t.miss(f"durable get({p!r}): got {_short(got)} want {_short(want)}", "read")

@@ -7,7 +7,9 @@ layout of data from ``bench/layouts/``) and a traffic mix
 (``bench/traffic/<name>.json``); each metric is read by
 ``bench/metrics/<name>.py``.  A run:
 
-1. builds the configuration's data from ``--seed`` through ``PathStore``;
+1. builds the configuration's data from ``--seed`` through ``PathStore``
+   into the store its ``store`` block names: an in-memory ``MemKV``, or a
+   ``DurableKV`` in a directory of the run's own, removed when it ends;
 2. freezes it with ``DeviceEngine.from_store``;
 3. warms up: the mix's warm-up waves, then every batch-size bucket of each
    read operation the mix issued (batches are padded to powers of two),
@@ -17,12 +19,17 @@ layout of data from ``bench/layouts/``) and a traffic mix
 4. measures for ``--seconds``: waves of ``BatchPlanner.flush()`` and
    ``DeviceEngine.refresh()`` driven by the mix's closed-loop clients
    (with ``--trace 1`` under the profiler, with the program's spans on);
-5. reads every written path back, then compares every answer of the run
-   with the plain reference (``refkv.py``) replayed wave by wave;
-6. prints the compared numbers with their limits, then one JSON line.
+5. on a durable store, copies its directory as a killed process would
+   leave it (``crash_image``);
+6. reads every written path back, then compares every answer of the run
+   with the plain reference (``refkv.py``) replayed wave by wave, and on a
+   durable store every written path of the reopened copy with the
+   reference after the last wave;
+7. prints the compared numbers with their limits, then one JSON line.
 
-A JAX backend that is not a TPU, or fewer chips than the cell asks for, is
-an error: the run prints no result and exits 2.
+A store block that names an unknown engine or leaves a setting out, a JAX
+backend that is not a TPU, or fewer chips than the cell asks for, is an
+error: the run prints no result and exits 2.
 """
 from __future__ import annotations
 
@@ -38,12 +45,13 @@ import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
-from contextlib import nullcontext  # noqa: E402
+from contextlib import ExitStack, nullcontext  # noqa: E402
 from dataclasses import dataclass, field  # noqa: E402
 from pathlib import Path  # noqa: E402
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
+LAYOUTS = BENCH / "layouts"
 if str(BENCH) not in sys.path:
     sys.path.insert(0, str(BENCH))
 
@@ -52,9 +60,19 @@ import numpy as np  # noqa: E402
 import compare  # noqa: E402
 import refkv  # noqa: E402
 
-#: every compared number must be at most its limit (exact comparisons)
+#: every compared number must be at most its limit (exact comparisons);
+#: ``durable_mismatches`` is compared on durable stores alone
 LIMITS = {"read_mismatches": 0, "write_mismatches": 0,
-          "readback_mismatches": 0, "unresolved": 0}
+          "readback_mismatches": 0, "unresolved": 0, "durable_mismatches": 0}
+#: each store engine a configuration may name, with the settings its
+#: ``store`` block must state (passed explicitly: no setting falls back to
+#: a default or to a ``REPRO_*`` environment variable)
+STORE_SETTINGS = {
+    "MemKV": ("memtable_limit",),
+    "DurableKV": ("shards", "memtable_limit", "sync", "level_ratio", "bloom_bits",
+                  "block_cache_bytes", "segment_target_bytes", "compact_budget_bytes",
+                  "bg_compact", "commit_pipeline", "shard_workers"),
+}
 
 
 def process_start() -> float:
@@ -75,6 +93,11 @@ def load_module(path: Path):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_layout(name: str):
+    """The layout module ``bench/layouts/<name>.py``."""
+    return load_module(LAYOUTS / f"{name}.py")
 
 
 def by_name(entries: list[dict], name: str, what: str) -> dict:
@@ -142,47 +165,102 @@ class CompileCounter:
             self.count += 1
 
 
-class GcPauses:
-    """Pauses of Python's cyclic collector while ``active``: (generation, ms)."""
-
-    def __init__(self):
-        self.active = False
-        self.pauses: list[tuple[int, float]] = []
-        self._t0 = 0.0
-        gc.callbacks.append(self._event)
-
-    def _event(self, phase, info):
-        if phase == "start":
-            self._t0 = time.perf_counter()
-        elif self.active:
-            self.pauses.append((info["generation"], (time.perf_counter() - self._t0) * 1e3))
-
-    def summary(self) -> dict:
-        """Per generation: [count, total ms, longest ms]."""
-        out = {}
-        for g in sorted({g for g, _ in self.pauses}):
-            ms = [m for gg, m in self.pauses if gg == g]
-            out[g] = [len(ms), round(sum(ms), 1), round(max(ms), 1)]
-        return out
+def store_error(config: dict) -> str | None:
+    """Why the configuration's ``store`` block names no store the harness
+    builds, or None."""
+    block = config.get("store", {})
+    engine = block.get("engine")
+    if engine not in STORE_SETTINGS:
+        return f"unknown store engine {engine!r} (known: {', '.join(STORE_SETTINGS)})"
+    missing = [k for k in STORE_SETTINGS[engine] if k not in block]
+    return f"the {engine} store block lacks {', '.join(missing)}" if missing else None
 
 
-def build_engine(layout, config: dict):
-    """The system under test: the layout's records through ``PathStore``
-    into an in-memory ``MemKV``, frozen by ``DeviceEngine.from_store`` with
-    a writer whose clock is the reference's constant."""
-    from repro.core.consistency import WikiWriter
-    from repro.core.engine import DeviceEngine
+def is_durable(config: dict) -> bool:
+    return config["store"]["engine"] == "DurableKV"
+
+
+def open_durable(root: str, block: dict, n_shards: int | None):
+    """``open_durable_store`` at ``root`` with every setting of ``block``."""
+    from repro.storage.lsm import open_durable_store
+
+    return open_durable_store(
+        root, n_shards=n_shards, memtable_limit=int(block["memtable_limit"]),
+        sync=str(block["sync"]), level_ratio=int(block["level_ratio"]),
+        bloom_bits=int(block["bloom_bits"]),
+        block_cache_bytes=int(block["block_cache_bytes"]),
+        segment_target_bytes=int(block["segment_target_bytes"]),
+        compact_budget_bytes=int(block["compact_budget_bytes"]),
+        bg_compact=bool(block["bg_compact"]), shard_workers=int(block["shard_workers"]),
+        commit_pipeline=bool(block["commit_pipeline"]))
+
+
+def build_store(layout, config: dict, root: Path):
+    """The layout's records through ``PathStore`` into the store that the
+    configuration names: an in-memory ``MemKV``, or a ``DurableKV`` (one a
+    shard) in ``root/store``."""
     from repro.core.store import MemKV, PathStore
 
     from loop import to_program
 
-    store = PathStore(MemKV(memtable_limit=int(config["store"]["memtable_limit"])))
+    if is_durable(config):
+        store = open_durable(str(root / "store"), config["store"],
+                             int(config["store"]["shards"]))
+    else:
+        store = PathStore(MemKV(memtable_limit=int(config["store"]["memtable_limit"])))
     for path, rec in layout.items:
         store.put_record(path, to_program(rec))
     store.flush()
+    return store
+
+
+def build_engine(store, config: dict):
+    """The system under test: the store frozen by ``DeviceEngine.from_store``
+    with a writer whose clock is the reference's constant."""
+    from repro.core.consistency import WikiWriter
+    from repro.core.engine import DeviceEngine
+
     writer = WikiWriter(store, clock=lambda: refkv.CLOCK)
     return DeviceEngine.from_store(store, writer=writer,
                                    refresh_cadence=int(config["refresh_cadence"]))
+
+
+def crash_image(store, root: Path) -> int:
+    """Copy the durable store's directory (``root/store``) to ``root/image``
+    as a killed process leaves it: neither closed nor flushed, once every
+    acknowledged wave is durable.  A refresh acknowledges its wave with a
+    synchronous group commit; a pipelined commit is acknowledged once the
+    store's commit sequencer has joined it, which the next wave's commit
+    would do first, so the copy waits for that join.  The operating
+    system's page cache survives a killed process, so the image is what a
+    process crash leaves, not a power loss: a missing fsync does not show
+    in it.  Each shard's lock is held over the copy, so that no compaction
+    worker changes a file while it is read.  Returns the image's bytes."""
+    join = getattr(store, "_drain_pipeline", None)
+    if join is not None:
+        join()
+    if store.durable_epoch() < store.last_epoch():
+        raise RuntimeError(f"epoch {store.last_epoch()} is committed, but only "
+                           f"{store.durable_epoch()} is durable")
+    with ExitStack() as held:
+        for shard in getattr(store, "shards", [store]):
+            held.enter_context(shard.engine._lock)
+        shutil.copytree(root / "store", root / "image")
+    return sum(f.stat().st_size for f in (root / "image").rglob("*") if f.is_file())
+
+
+def read_image(root: Path, block: dict, checker, paths: list[str]) -> float:
+    """Reopen the crash image (recovery from its files alone) and compare
+    each path's record with the reference's (the checker's ``durable``
+    tally); returns the seconds the reopen took."""
+    t0 = time.perf_counter()
+    image = open_durable(str(root / "image"), block, None)
+    recover_s = time.perf_counter() - t0
+    try:
+        compare.durable(checker, image.get, paths)
+    finally:
+        image.close()
+    return recover_s
 
 
 def warm_ops(layout, op: str, n: int) -> list[tuple]:
@@ -308,21 +386,32 @@ def warm_up(loop, layout, traffic: dict, seconds: float) -> None:
 def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
              t_origin: float | None = None, engine_hook=None, log=print) -> dict:
     """One run of a cell; returns the result object.  ``engine_hook(engine)``
-    may change or wrap the engine after the freeze."""
+    may change or wrap the engine after the freeze.  The run's files (a
+    durable store, its crash image, the trace) live in a directory of its
+    own, removed when the run ends."""
+    with tempfile.TemporaryDirectory(prefix="wikikv-run-") as root:
+        return _run_cell(cell, seed, seconds, trace, Path(root),
+                         t_origin=T_IMPORT if t_origin is None else t_origin,
+                         engine_hook=engine_hook, log=log)
+
+
+def _run_cell(cell: Cell, seed: int, seconds: float, trace: bool, root: Path, *,
+              t_origin: float, engine_hook, log) -> dict:
     import jax
 
     from loadgen import LoadGen
     from loop import WaveLoop, annotate_engine
 
-    t_origin = T_IMPORT if t_origin is None else t_origin
     counter = CompileCounter()
-    collector = GcPauses()
+    durable = is_durable(cell.config)
     streams = np.random.SeedSequence(seed % 2 ** 64).spawn(2)
     stamps = {"start": time.time()}
-    layout = load_module(BENCH / "layouts" / f"{cell.config['layout']}.py").build(
+    layout = load_layout(cell.config["layout"]).build(
         cell.config, np.random.default_rng(streams[0]))
     stamps["layout"] = time.time()
-    engine = build_engine(layout, cell.config)
+    store = build_store(layout, cell.config, root)
+    stamps["store"] = time.time()
+    engine = build_engine(store, cell.config)
     stamps["engine"] = time.time()
     if engine_hook is not None:
         engine = engine_hook(engine)
@@ -335,18 +424,16 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
 
     # -- measured window ------------------------------------------------------
     calls: list = []
-    tmp = None
     if trace:
         from repro import obs
 
-        tmp = tempfile.mkdtemp(prefix="wikikv-bench-")
         annotate_engine(engine, annotate, calls)
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
-        jax.profiler.start_trace(tmp, profiler_options=opts)
+        jax.profiler.start_trace(str(root / "trace"), profiler_options=opts)
         obs.configure(enabled=True, ring_size=1 << 22)
     first = len(loop.waves)
-    counter.active = collector.active = True
+    counter.active = True
     cpu0 = time.process_time()
     t_window = time.time()
     setup_s = t_window - t_origin
@@ -354,7 +441,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         deadline = time.perf_counter() + seconds
         while loop.wave("window").t_end < deadline:
             pass
-    counter.active = collector.active = False
+    counter.active = False
     cpu_s = time.process_time() - cpu0
     waves = loop.waves[first:]
     n_calls = len(calls)
@@ -369,7 +456,6 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
                     "warmup_waves": first,
                     "setup_parts_s": {b: round(stamps[b] - stamps[a], 3)
                                       for a, b in zip(names, names[1:])},
-                    "gc_in_window": collector.summary(),
                     "cpu_per_wall": round(cpu_s / window_s, 3),
                     "wave_flush_refresh_ms": [[round((w.t_flush_end - w.t_start) * 1e3),
                                                round((w.t_end - w.t_flush_end) * 1e3)]
@@ -377,6 +463,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     dev = jax.devices()
     memory_peak = max((d.memory_stats().get("peak_bytes_in_use", 0)
                        for d in dev if d.memory_stats()), default=0)
+    image_bytes = crash_image(store, root) if durable else None
 
     # -- read-back of every written path --------------------------------------
     written = list(dict.fromkeys(e[1] for w in loop.waves for e in w.entries
@@ -389,12 +476,15 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     view = window_view(waves, loop.finished, setup_s, spans=spans, calls=calls[:n_calls])
     breakdown = device_extra = None
     if trace:
-        import xplane
+        import program_spans
         import roofline
+        import xplane
 
-        files = sorted(Path(tmp).rglob("*.xplane.pb"))
-        view.trace = xplane.load(str(files[-1]))
-        shutil.rmtree(tmp, ignore_errors=True)
+        from jax.profiler import ProfileData
+
+        path = str(sorted((root / "trace").rglob("*.xplane.pb"))[-1])
+        data = ProfileData.from_file(path)
+        view.trace = xplane.load(path, data)
         view.trace_window = view.trace.window()
         view.peak = roofline.peaks(dev[0].device_kind)
         live = [p for p, _ in layout.items]
@@ -409,7 +499,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         device_extra = {"busy_s": xplane.busy(view.trace, lo, hi) / 1e9,
                         "window_s": (hi - lo) / 1e9}
         breakdown = {"device_ops": xplane.top_programs(view.trace, lo, hi),
-                     "idle_gaps": xplane.idle_by_host(view.trace, lo, hi)}
+                     "idle_gaps": program_spans.idle_by_span(
+                         view.trace, program_spans.load(path, view.trace, data), lo, hi)}
+        del data
+        shutil.rmtree(root / "trace", ignore_errors=True)
     metrics = read_metrics(cell.per_layer if trace else cell.end_to_end, view)
     attempted = sum(len(w.entries) for w in waves)
     failed = sum(1 for w in waves for e in w.entries
@@ -418,10 +511,19 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     # -- correctness: free the program, replay the reference -----------------
     all_waves = loop.waves
     del loop, engine, gen
+    if durable:
+        store.close()
+    del store
     gc.collect()
-    tallies = compare.check(layout.items, all_waves)
-    checks, correct = judge(tallies)
-    log(json.dumps({"after_window_s": round(time.time() - t_window - seconds, 3)}), flush=True)
+    checker = compare.replay(layout.items, all_waves)
+    after = {}
+    if durable:
+        after["recover_s"] = read_image(root, cell.config["store"], checker,
+                                        compare.written_paths(all_waves))
+        after["image_bytes"] = image_bytes
+    checks, correct = judge(checker.tallies)
+    log(json.dumps({"after_window_s": round(time.time() - t_window - seconds, 3), **after}),
+        flush=True)
     device = {"platform": dev[0].platform, "kind": dev[0].device_kind,
               "count": len(dev), "memory_peak_bytes": int(memory_peak)}
     if device_extra:
@@ -433,17 +535,19 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     result["tallies"] = {k: {f: getattr(t, f) for f in
                              ("reads", "read_mismatches", "writes",
                               "write_mismatches", "unresolved")}
-                         for k, t in tallies.items()}
-    result["examples"] = [x for t in tallies.values() for x in t.examples][:5]
+                         for k, t in checker.tallies.items()}
+    result["examples"] = [x for t in checker.tallies.values() for x in t.examples][:5]
     result["checks"] = checks
     return result
 
 
 def judge(tallies: dict) -> tuple[dict, bool]:
     """The compared numbers of the run (warm-up, the measured window, the
-    read-back), each with its limit, and the verdict."""
-    run = [t for p, t in tallies.items() if p != "readback"]
+    read-back, and on a durable store the crash image), each with its
+    limit, and the verdict."""
     back = tallies.get("readback", compare.Tally())
+    image = tallies.get("durable")
+    run = [t for p, t in tallies.items() if p not in ("readback", "durable")]
     values = {
         "read_mismatches": sum(t.read_mismatches for t in run),
         "write_mismatches": sum(t.write_mismatches for t in run),
@@ -451,6 +555,9 @@ def judge(tallies: dict) -> tuple[dict, bool]:
         "unresolved": sum(t.unresolved for t in run) + back.unresolved,
     }
     compared = sum(t.reads + t.writes for t in run) + back.reads
+    if image is not None:
+        values["durable_mismatches"] = image.read_mismatches
+        compared += image.reads
     checks = {k: {"value": v, "limit": LIMITS[k]} for k, v in values.items()}
     return checks, compared > 0 and all(v <= LIMITS[k] for k, v in values.items())
 
@@ -478,6 +585,10 @@ def main(argv=None, engine_hook=None) -> int:
     args = ap.parse_args(argv)
     t_origin = process_start()
     cell = Cell.load(args.workload)
+    error = store_error(cell.config)
+    if error:
+        print(f"harness: {error}", file=sys.stderr)
+        return 2
     if not (ROOT / "src" / "repro").is_dir():
         print(f"harness: the program (src/repro) is not in {ROOT}", file=sys.stderr)
         return 2

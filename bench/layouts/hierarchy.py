@@ -8,7 +8,7 @@ bodies chosen by the CRC-32 of the page's path.
 
 Config keys: ``pages``, ``topics_per_dimension``, ``pages_per_topic``,
 ``vocabulary``, ``page_text_bytes``.  Populations: ``page`` (leaf paths)
-and ``topic``.
+and ``topic``.  ``tiny`` gives the configuration's CPU rehearsal size.
 """
 from __future__ import annotations
 
@@ -110,3 +110,8 @@ class Hierarchy:
 
 def build(config: dict, rng: np.random.Generator) -> Hierarchy:
     return Hierarchy(config, rng)
+
+
+def tiny(config: dict) -> dict:
+    """The configuration at its CPU rehearsal size: two dimensions."""
+    return dict(config, pages=2 * config["topics_per_dimension"] * config["pages_per_topic"])

@@ -6,7 +6,8 @@ Keys follow CoreWorkload's hashed insert order: record k is named ``user``
 followed by the FNV-1a 64-bit hash of k's eight little-endian bytes, read
 as a signed 64-bit number and made non-negative (``Utils.fnvhash64``).
 Config keys: ``table``, ``recordcount``, ``fieldcount``, ``fieldlength``,
-``groups``.  Population: ``record`` (the loaded keys).
+``groups``.  Population: ``record`` (the loaded keys).  ``tiny`` gives
+the configuration's CPU rehearsal size.
 """
 from __future__ import annotations
 
@@ -94,3 +95,8 @@ class YcsbTable:
 
 def build(config: dict, rng: np.random.Generator) -> YcsbTable:
     return YcsbTable(config, rng)
+
+
+def tiny(config: dict) -> dict:
+    """The configuration at its CPU rehearsal size: 4,096 records in 64 groups."""
+    return dict(config, recordcount=4096, groups=64)

@@ -21,6 +21,9 @@ stretch of a run the same set of limits, in another order.
 
   admit   ``count`` new children under ``parent`` keys
   update  ``count`` rewrites of ``target`` keys
+  unlink  ``count`` ``target`` keys retired, dealt from a seeded shuffle of
+          the population, so that each is unlinked at most once a run; a
+          retired key is no longer drawn as a target
 
 Clients start their first requests spread evenly over the mix's first
 ``stagger_waves`` waves (0 where it names none): clients that all start at
@@ -71,6 +74,8 @@ class LoadGen:
         self._choosers: dict[str, KeyChooser] = {}
         self._kinds: list[dict] = []
         self._decks: dict[tuple[int, int], list[int]] = {}
+        self._unlink_decks: dict[str, list[int]] = {}
+        self.retired: set[str] = set()
         self.inserted = 0
         self.updated = 0
 
@@ -81,7 +86,23 @@ class LoadGen:
         if ch is None:
             ch = self._choosers[population] = KeyChooser(
                 len(keys), self.spec["key_distribution"], self.rng)
-        return keys[int(ch.draw(1)[0])]
+        while True:
+            k = keys[int(ch.draw(1)[0])]
+            if k not in self.retired:
+                return k
+
+    def retire(self, population: str) -> str:
+        """The next key of the population's shuffled unlink deck, retired.
+        The deck's last key stays, so that targets can still be drawn."""
+        deck = self._unlink_decks.get(population)
+        if deck is None:
+            n = len(self.layout.populations[population])
+            deck = self._unlink_decks[population] = self.rng.permutation(n).tolist()
+        if len(deck) <= 1:
+            raise ValueError(f"the mix would unlink every {population!r} key")
+        k = self.layout.populations[population][deck.pop()]
+        self.retired.add(k)
+        return k
 
     def _next_kind(self) -> dict:
         if not self._kinds:
@@ -139,6 +160,9 @@ class LoadGen:
                 for _ in range(count):
                     path = self.key(w["target"])
                     client.update(path, self._update_spec(path))
+            elif kind == "unlink":
+                for _ in range(count):
+                    client.unlink(self.retire(w["target"]))
             else:
                 raise ValueError(f"unknown per-wave write {kind!r}")
 

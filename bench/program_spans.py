@@ -77,12 +77,13 @@ def op_name(event_name: str) -> str:
     return base if base and suffix.isdigit() else head
 
 
-def load(path: str, trace: xplane.Trace) -> ProgramTrace:
-    """The program's spans and the device's ops of the trace at ``path``,
-    shifted by the clock offset ``xplane.load`` found for ``trace``."""
+def load(path: str, trace: xplane.Trace, data=None) -> ProgramTrace:
+    """The program's spans and the device's ops of the trace at ``path``
+    (``data`` its ``ProfileData`` where already parsed), shifted by the
+    clock offset ``xplane.load`` found for ``trace``."""
     from jax.profiler import ProfileData
 
-    data = ProfileData.from_file(path)
+    data = data or ProfileData.from_file(path)
     spans: list = []
     ops: dict = {}
     for plane in data.planes:
@@ -126,16 +127,14 @@ def op_seconds(prog: ProgramTrace, lo: float, hi: float, n: int = 10) -> list:
     return [[name, ns / k / 1e9] for name, ns in ranked]
 
 
-def outside_share(trace: xplane.Trace, prog: ProgramTrace, program: str, op: str,
-                  lo: float, hi: float) -> float | None:
-    """Percent of the device time of ``program`` in [lo, hi] spent outside
-    its op ``op`` (an op that runs in no other program); None where the
-    program did not run."""
-    whole = xplane.length(xplane.merge(xplane.clip(
-        [(s, e) for evs in trace.devices.values() for s, e, name, _ in evs
-         if name == program], lo, hi)))
-    if whole <= 0:
-        return None
-    inside = xplane.length(xplane.merge(xplane.clip(
-        [(s, e) for evs in prog.ops.values() for s, e, name in evs if name == op], lo, hi)))
-    return 100.0 * (whole - inside) / whole
+def program_share(trace: xplane.Trace, part: str, other: str, lo: float, hi: float
+                  ) -> float | None:
+    """Percent of the device time of programs ``part`` and ``other`` in
+    [lo, hi] that ``part`` took; None where neither ran."""
+    def device_ns(program):
+        return xplane.length(xplane.merge(xplane.clip(
+            [(s, e) for evs in trace.devices.values() for s, e, name, _ in evs
+             if name == program], lo, hi)))
+
+    a, b = device_ns(part), device_ns(other)
+    return 100.0 * a / (a + b) if a + b > 0 else None

@@ -103,16 +103,20 @@ def test_idle_by_span_labels_gaps_with_the_innermost_event():
     assert program_spans.idle_by_span(t, bare, 0, 100) == xplane.idle_by_host(t, 0, 100)
 
 
-def test_outside_share_by_hand():
-    t = xplane.Trace(devices={"/device:TPU:0": [(0, 100, "jit_scan", None),
-                                                (200, 300, "jit_other", None)]},
-                     annotations=[])
-    prog = program_spans.ProgramTrace(spans=[], ops={"/device:TPU:0": [
-        (0, 30, "pack"), (30, 90, "scan_kernel"), (90, 100, "slice"),
-        (200, 300, "other_kernel")]})
-    assert program_spans.outside_share(t, prog, "jit_scan", "scan_kernel", 0, 1000) == \
-        pytest.approx(40.0)
-    assert program_spans.outside_share(t, prog, "jit_none", "scan_kernel", 0, 1000) is None
+def test_scan_pack_share_by_hand():
+    """The pack's share of the two packed-scan programs' device time, on
+    both planes, clipped to the window; other programs do not count."""
+    pack, scan = trace_split.PACK_PROGRAM, trace_split.SCAN_PROGRAM
+    t = xplane.Trace(devices={
+        "/device:TPU:0": [(0, 100, pack, None), (100, 400, scan, None),
+                          (400, 900, "jit_path_lookup", None), (900, 1200, scan, None)],
+        "/device:TPU:1": [(0, 100, pack, None), (100, 400, scan, None)]}, annotations=[])
+    # both planes' packs overlap in time and count once: 100 of 100 + 600
+    assert program_spans.program_share(t, pack, scan, 0, 1200) == pytest.approx(100 / 7)
+    # the window cuts the last scan to 100 ns
+    assert program_spans.program_share(t, pack, scan, 50, 1000) == \
+        pytest.approx(100 * 50 / (50 + 300 + 100))
+    assert program_spans.program_share(t, pack, scan, 400, 900) is None
 
 
 @pytest.fixture(scope="module")
@@ -136,12 +140,9 @@ def test_recorded_trace_reduces_as_before(recorded):
     runs = trace.devices["/device:TPU:0"]
     for s, e, _ in prog.ops["/device:TPU:0"]:
         assert any(rs - 1 <= s and e <= re + 1 for rs, re, _, _ in runs)
-    share = program_spans.outside_share(trace, prog, "jit_prefix_search", "prefix_search",
-                                        lo, hi)
-    scan = sum(e - s for s, e, n in prog.ops["/device:TPU:0"] if n == "prefix_search")
-    whole = sum(e - s for s, e, n, _ in runs if n == "jit_prefix_search")
-    assert share == pytest.approx(100 * (whole - scan) / whole)
-    assert 0 < share < 100
+    # a recording from before the packed scan: the pack share reads nothing
+    assert program_spans.program_share(trace, trace_split.PACK_PROGRAM,
+                                       trace_split.SCAN_PROGRAM, lo, hi) is None
 
 
 def test_cpu_profile_holds_the_program_spans_inside_the_harness_annotation(tmp_path):
@@ -195,9 +196,9 @@ def test_trace_split_reads_a_tiny_traced_run(monkeypatch):
     found = {}
     load = xplane.load
 
-    def load_and_split(path):
-        trace = load(path)
-        found.update(trace_split.split(path, trace))
+    def load_and_split(path, data=None):
+        trace = load(path, data)
+        found.update(trace_split.split(path, trace, data))
         return trace
 
     monkeypatch.setattr(xplane, "load", load_and_split)

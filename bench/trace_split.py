@@ -12,8 +12,9 @@ from the same trace:
 - ``stage_cover``: the share of the ``device.<read>`` spans' time that
   their ``device.prep``, ``device.wait`` and ``device.map`` spans cover;
 - ``device_ops``: the device ops that ran longest, by op name;
-- ``scan_pack_share``: the share of ``jit_prefix_search``'s device time
-  outside its ``prefix_search_scan`` kernel (packing the path table).
+- ``scan_pack_share``: the share of the Q4 scan's device time spent
+  packing the path table: ``jit_pack_scan_table`` (once an epoch) over it
+  and ``jit_prefix_search_packed`` (once a call) together.
 """
 from __future__ import annotations
 
@@ -30,12 +31,13 @@ import harness  # noqa: E402
 import program_spans  # noqa: E402
 import xplane  # noqa: E402
 
-SCAN_PROGRAM, SCAN_KERNEL = "jit_prefix_search", "prefix_search_scan"
+PACK_PROGRAM, SCAN_PROGRAM = "jit_pack_scan_table", "jit_prefix_search_packed"
 
 
-def split(path: str, trace: xplane.Trace) -> dict:
-    """The program's part of the breakdown of the trace at ``path``."""
-    prog = program_spans.load(path, trace)
+def split(path: str, trace: xplane.Trace, data=None) -> dict:
+    """The program's part of the breakdown of the trace at ``path``
+    (``data`` its parsed ``ProfileData``, where the caller has it)."""
+    prog = program_spans.load(path, trace, data)
     lo, hi = trace.window()
     inside = [(s, e, n) for s, e, n in prog.spans if s < hi and e > lo]
     calls = sum(e - s for s, e, n in inside if n in program_spans.READ_CALLS)
@@ -43,8 +45,8 @@ def split(path: str, trace: xplane.Trace) -> dict:
     return {"idle_gaps": program_spans.idle_by_span(trace, prog, lo, hi, n=16),
             "stage_cover": stages / calls if calls else None,
             "device_ops": program_spans.op_seconds(prog, lo, hi),
-            "scan_pack_share": program_spans.outside_share(
-                trace, prog, SCAN_PROGRAM, SCAN_KERNEL, lo, hi)}
+            "scan_pack_share": program_spans.program_share(
+                trace, PACK_PROGRAM, SCAN_PROGRAM, lo, hi)}
 
 
 def main(argv=None) -> int:
@@ -57,9 +59,9 @@ def main(argv=None) -> int:
     found: dict = {}
     load, read = xplane.load, harness.read_metrics
 
-    def load_and_split(path):
-        trace = load(path)
-        found.update(split(path, trace))
+    def load_and_split(path, data=None):
+        trace = load(path, data)
+        found.update(split(path, trace, data))
         return trace
 
     xplane.load = load_and_split

@@ -67,10 +67,12 @@ def offset(programs, enqueued: dict, completed: dict) -> float:
     return mids[len(mids) // 2]
 
 
-def load(path: str) -> Trace:
+def load(path: str, data=None) -> Trace:
+    """The trace at ``path``; ``data`` is its ``ProfileData`` where the
+    caller has parsed it already."""
     from jax.profiler import ProfileData
 
-    data = ProfileData.from_file(path)
+    data = data or ProfileData.from_file(path)
     raw: dict[str, list] = {}
     enqueued: dict = {}
     completed: dict = {}
